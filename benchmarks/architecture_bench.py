@@ -20,7 +20,6 @@ _CHILD = r"""
 import time, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.parameter_server import make_ps_step
-from repro.core.collectives import shard_map
 N = 1_000_000
 mesh = Mesh(np.array(jax.devices()).reshape(8), ("w",))
 params = {"w": jax.random.normal(jax.random.PRNGKey(0), (N,))}
@@ -29,14 +28,14 @@ grads = {"w": jnp.stack([jnp.full((N,), float(i)) for i in range(8)])}
 def update(p, g, o):
     return jax.tree.map(lambda a, b: a - 0.1 * b, p, g), o
 ps = make_ps_step(update, "w")
-f_ps = jax.jit(shard_map(
+f_ps = jax.jit(jax.shard_map(
     lambda p, g: ps(p, jax.tree.map(lambda a: a[0], g), None)[0],
     mesh=mesh, in_specs=(P(), P("w")), out_specs=P(), check_vma=False))
 
 def dec(p, g):
     gsum = jax.lax.psum(jax.tree.map(lambda a: a[0], g)["w"], "w")
     return {"w": p["w"] - 0.1 * gsum}
-f_dec = jax.jit(shard_map(dec, mesh=mesh, in_specs=(P(), P("w")),
+f_dec = jax.jit(jax.shard_map(dec, mesh=mesh, in_specs=(P(), P("w")),
                 out_specs=P(), check_vma=False))
 for name, f in [("ps", f_ps), ("decentralized", f_dec)]:
     jax.block_until_ready(f(params, grads))

@@ -22,11 +22,10 @@ _CHILD = r"""
 import time, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.allreduce import TOPOLOGIES
-from repro.core.collectives import shard_map
 mesh = Mesh(np.array(jax.devices()).reshape(8), ("w",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, %d))
 for name, fn in TOPOLOGIES.items():
-    f = jax.jit(shard_map(lambda a, _fn=fn: _fn(a[0], "w")[None],
+    f = jax.jit(jax.shard_map(lambda a, _fn=fn: _fn(a[0], "w")[None],
                 mesh=mesh, in_specs=P("w", None), out_specs=P("w", None),
                 check_vma=False))
     f(x).block_until_ready()

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.federated import FedConfig, run_fedavg
+from repro.launch.env import use_compile_cache
 from repro.data.partition import (dirichlet_partition, iid_partition,
                                   label_skew, make_classification_data)
 
@@ -15,6 +16,7 @@ N, DIM, CLASSES, CLIENTS = 1500, 16, 8, 10
 
 
 def main():
+    use_compile_cache()
     X, y = make_classification_data(N, DIM, CLASSES, seed=0)
 
     def grad_fn(params, batch):

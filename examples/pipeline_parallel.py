@@ -15,11 +15,12 @@ import jax.numpy as jnp                           # noqa: E402
 import numpy as np                                # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
-from repro.core.collectives import shard_map                   # noqa: E402
 from repro.core.pipeline import bubble_fraction, gpipe_forward  # noqa: E402
+from repro.launch.env import use_compile_cache  # noqa: E402
 
 
 def main():
+    use_compile_cache()
     n_stages = 4
     d = 64
     mesh = Mesh(np.array(jax.devices()[:n_stages]), ("stage",))
@@ -31,7 +32,7 @@ def main():
 
     for n_micro in (1, 4, 16):
         xm = jax.random.normal(key, (n_micro, 8, d))
-        f = shard_map(
+        f = jax.shard_map(
             lambda w, x: gpipe_forward(stage_fn, w[0], x, "stage")[None],
             mesh=mesh, in_specs=(P("stage"), P(None)), out_specs=P("stage"),
             check_vma=False)

@@ -23,6 +23,7 @@ from repro.checkpoint import ModelRegistry, load_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.core.precision import PrecisionPolicy
 from repro.data import LMDataConfig, make_lm_batches
+from repro.launch.env import use_compile_cache
 from repro.models import build_model
 from repro.optim import Adam
 from repro.serve import generate
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--strategy", default="bsp/allreduce/onebit@1",
                     help="sync[:staleness]/arch/comp[:density]@workers")
     args = ap.parse_args()
+    use_compile_cache()
     # like train_100m_e2e: a spec without "@N" means 1 worker here, not
     # Strategy's default of 4 — keeps --strategy bsp/allreduce/dgc on the
     # single-worker Adam path

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.env import use_compile_cache
 from repro.models import build_model
 from repro.serve.cache import cache_bytes
 from repro.serve.engine import ServeConfig, ServeEngine
@@ -41,6 +42,7 @@ def run(arch: str, window: int = 0, page_size: int = 0):
 
 
 def main():
+    use_compile_cache()
     print("arch (decode mode)                          time   cache")
     full, toks_full = run("tinyllama-1.1b")           # full KV cache
     swa, _ = run("tinyllama-1.1b", window=8)          # sliding window

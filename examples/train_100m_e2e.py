@@ -20,7 +20,8 @@ The parallel-training strategy is one declarative spec string
                                       tensor/stage mesh axes need a
                                       StagedModel — docs/hybrid.md)
 
-Multi-worker specs re-exec with that many virtual host devices.
+Under ``JAX_PLATFORMS=cpu``, multi-worker specs re-exec with that many
+virtual host devices; on an accelerator host the workers are its chips.
 
 ``--failure-plan "crash:w1@5,resize:4@10"`` demonstrates elastic
 fault-tolerant training end to end: the run snapshots through
@@ -50,7 +51,11 @@ def _spec_workers(spec: str) -> int:
 
 
 def _maybe_reexec_with_devices():
-    """Virtual host devices must be configured before jax import."""
+    """Virtual host devices must be configured before jax import.  Only a
+    CPU run gets them: a chip host's devices are its chips, and a child
+    process could not reach a chip this one holds."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
     ap = argparse.ArgumentParser(add_help=False)
     ap.add_argument("--strategy", default="bsp/allreduce/none@1")
     n = _spec_workers(ap.parse_known_args()[0].strategy)
@@ -73,6 +78,7 @@ from repro.checkpoint import ModelRegistry, save_checkpoint   # noqa: E402
 from repro.configs import get_config              # noqa: E402
 from repro.core.precision import PrecisionPolicy  # noqa: E402
 from repro.data import LMDataConfig, make_lm_batches  # noqa: E402
+from repro.launch.env import use_compile_cache  # noqa: E402
 from repro.models import build_model              # noqa: E402
 from repro.optim import AdamW                     # noqa: E402
 from repro.optim.schedule import cosine_warmup    # noqa: E402
@@ -193,6 +199,7 @@ def main():
                          " actually exchanged (device cells only)")
     ap.add_argument("--out", default="results/train_100m")
     args = ap.parse_args()
+    use_compile_cache()
     # workers default must agree with the pre-jax re-exec hook, which
     # reads only the "@N" suffix (no "@N" -> 1 worker, not Strategy's 4)
     strat = Strategy.parse(args.strategy,

@@ -48,7 +48,6 @@ from repro.comm.transport import (SCHEDULES, compressed_allreduce,
                                   compressed_reduce_scatter_ef,
                                   fp32_schedule_bytes, pad_for_schedule,
                                   schedule_tx_bytes)
-from repro.core.collectives import axis_size
 from repro.core.comm_scheduler import (LayerCost, LinkModel, bucketize,
                                        random_order, schedule_no_overlap,
                                        schedule_overlap, tictac_order)
@@ -88,12 +87,20 @@ def plan_buckets(params_example, bucket_mb: float, order: str,
 def modeled_event_bytes(compressor: Compressor, params_example) -> int:
     """The compressor's analytic per-push accounting over
     ``params_example`` (what the simulator reports) — the single
-    implementation every engine's modeled wire increment uses."""
-    zeros = jax.tree.map(lambda x: jnp.zeros(np.shape(x), jnp.float32),
-                         params_example)
-    state = compressor.init_state(zeros)
-    _, _, wb = compressor.roundtrip(zeros, state, jax.random.PRNGKey(0))
-    return int(wb)
+    implementation every engine's modeled wire increment uses.  The
+    count is static in the shapes, so the roundtrip is only traced:
+    nothing is allocated or run on the device."""
+    counted = []
+
+    def trace(zeros):
+        state = compressor.init_state(zeros)
+        counted.append(compressor.roundtrip(zeros, state,
+                                            jax.random.PRNGKey(0))[2])
+
+    jax.eval_shape(trace, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.float32),
+        params_example))
+    return int(counted[0])
 
 
 def scatter_flat(flat, idxs, leaf_shapes, out, dtype=None):
@@ -192,7 +199,7 @@ class CommPlan:
         ``make_bucketed_allreduce``.  Call inside ``shard_map``."""
         reduce_leaf = SCHEDULES[self.topology]
         leaves = jax.tree.leaves(grads)
-        n = axis_size(self.axis)
+        n = jax.lax.axis_size(self.axis)
         rdt = jnp.dtype(self.reduce_dtype)
         out: List[Any] = [None] * len(leaves)
         for b in self.order:                   # the executed schedule

@@ -53,45 +53,74 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.comm.codecs import NoneCodec, SegmentCodec
-from repro.core.collectives import axis_size
+
+
+# ======================================================= chunk layout
+# The ring and halving schedules address chunk ``(me - i) % n`` of a
+# buffer split into ``n`` chunks.  Stored as ``[n, m]``, the chunk axis
+# falls inside the TPU's (8, 128) tile, so every dynamic chunk read or
+# update cuts across all tiles, and the TPU compiler's codegen time grew
+# with the bytes reduced (compiling a 1-layer tinyllama grad step with a
+# per-leaf ring for four v5e chips: 65 s, against 6.5 s with psum and
+# 8.6 s in this layout).  Chunks are kept as ``[n, R, 128]``
+# rows instead, each zero-padded to whole rows: a chunk is one
+# contiguous block, and the padding never leaves the device.
+LANES = 128
+
+
+def _rows(flat, count: int):
+    """``count`` chunks, flat ``[count * m]``, as ``[count, R, LANES]``."""
+    x = flat.reshape(count, -1)
+    x = jnp.pad(x, ((0, 0), (0, (-x.shape[1]) % LANES)))
+    return x.reshape(count, -1, LANES)
+
+
+def _flat(rows, m: int):
+    """Inverse of ``_rows``: ``[count, R, LANES]`` -> ``[count * m]``."""
+    return rows.reshape(rows.shape[0], -1)[:, :m].reshape(-1)
+
+
+def _take(rows, start, count: int, m: int):
+    """Chunks ``start .. start + count - 1`` of ``rows``, flat."""
+    return _flat(lax.dynamic_slice_in_dim(rows, start, count), m)
 
 
 # ===================================================== exact schedules
-# (the pre-refactor core/allreduce.py implementations, moved verbatim;
-# core/allreduce.py re-exports them so existing call sites — and bitwise
-# behaviour — are unchanged)
+# (the pre-refactor core/allreduce.py implementations; core/allreduce.py
+# re-exports them so existing call sites — and bitwise behaviour — are
+# unchanged)
 def ring_allreduce(x, axis_name: str):
     """Bandwidth-optimal ring: reduce-scatter then all-gather, 2(n-1) steps."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     me = lax.axis_index(axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1)
-    pad = (-flat.shape[0]) % n
-    chunks = jnp.pad(flat, (0, pad)).reshape(n, -1)
+    m = -(-flat.shape[0] // n)
+    chunks = _rows(jnp.pad(flat, (0, n * m - flat.shape[0])), n)
     fwd = [(i, (i + 1) % n) for i in range(n)]
 
     def rs_step(i, c):
-        send = c[(me - i) % n]
+        send = _take(c, (me - i) % n, 1, m)
         recv = lax.ppermute(send, axis_name, fwd)
-        return c.at[(me - i - 1) % n].add(recv)
+        return c.at[(me - i - 1) % n].add(_rows(recv, 1)[0])
 
     chunks = lax.fori_loop(0, n - 1, rs_step, chunks)
     # rank r now owns reduced chunk (r + 1) % n
 
     def ag_step(i, c):
-        send = c[(me + 1 - i) % n]
+        send = _take(c, (me + 1 - i) % n, 1, m)
         recv = lax.ppermute(send, axis_name, fwd)
-        return c.at[(me - i) % n].set(recv)
+        return c.at[(me - i) % n].set(_rows(recv, 1)[0])
 
     chunks = lax.fori_loop(0, n - 1, ag_step, chunks)
-    return chunks.reshape(-1)[:flat.shape[0]].reshape(shape).astype(dtype)
+    return _flat(chunks, m)[:flat.shape[0]].reshape(shape).astype(dtype)
 
 
 def butterfly_allreduce(x, axis_name: str):
     """Recursive doubling: log2(n) exchange-and-add rounds (n power of 2)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     assert n & (n - 1) == 0, "butterfly requires power-of-two workers"
@@ -105,7 +134,7 @@ def butterfly_allreduce(x, axis_name: str):
 
 def tree_allreduce(x, axis_name: str):
     """Binomial tree: reduce to rank 0, then broadcast back down."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     me = lax.axis_index(axis_name)
@@ -161,11 +190,11 @@ def _where_planes(cond, new: Dict[str, Any], old: Dict[str, Any]):
 
 def _ring_rs(flat, axis_name: str, codec: SegmentCodec, key, n: int):
     """Compressed ring reduce-scatter: rank r ends owning reduced chunk r.
-    Returns (chunks [n, m] with c[me] reduced, residual [n, m],
-    sent_elems, key)."""
+    Returns (chunks as ``_rows`` with chunk me reduced, residual as
+    ``_rows``, sent_elems, key)."""
     me = lax.axis_index(axis_name)
     m = flat.shape[0] // n
-    c = flat.reshape(n, m)
+    c = _rows(flat, n)
     res = jnp.zeros_like(c)
     sent = jnp.zeros((), jnp.int32)
     fwd = [(i, (i + 1) % n) for i in range(n)]
@@ -174,39 +203,41 @@ def _ring_rs(flat, axis_name: str, codec: SegmentCodec, key, n: int):
         c, res, sent, key = carry
         key, sub = jax.random.split(key)
         pos = (me - i - 1) % n
-        send = c[pos]
+        send = _take(c, pos, 1, m)
         # fused encode + hop EF: one read of the chunk yields the planes
         # and the quantization residual (send - decode) together
         planes, r = codec.encode_ef(send, sub)
-        res = res.at[pos].add(r)
+        res = res.at[pos].add(_rows(r, 1)[0])
         sent = sent + codec.sent_elems(planes)
         planes = _permute(planes, axis_name, fwd)
         recv = codec.decode(planes)[:m]
-        return c.at[(me - i - 2) % n].add(recv), res, sent, key
+        return (c.at[(me - i - 2) % n].add(_rows(recv, 1)[0]), res, sent,
+                key)
 
     return lax.fori_loop(0, n - 1, step, (c, res, sent, key))
 
 
-def _owner_encode(c, res, pos, codec: SegmentCodec, key):
-    """Encode chunk ``pos`` once at its owner (EF the encode error) and
-    replace it with its own decode so every worker — owner included —
-    consumes identical bytes.  Encoding is not itself a transmission:
-    the caller's distribution loop counts every send of these planes."""
-    m = c.shape[1]
-    planes = codec.encode(c[pos], key)
+def _owner_encode(c, res, pos, m: int, codec: SegmentCodec, key):
+    """Encode chunk ``pos`` (of length ``m``, in ``_rows`` layout) once at
+    its owner (EF the encode error) and replace it with its own decode so
+    every worker — owner included — consumes identical bytes.  Encoding
+    is not itself a transmission: the caller's distribution loop counts
+    every send of these planes."""
+    x = _take(c, pos, 1, m)
+    planes = codec.encode(x, key)
     dec = codec.decode(planes)[:m]
-    res = res.at[pos].add(c[pos] - dec)
-    return c.at[pos].set(dec), res, planes
+    res = res.at[pos].add(_rows(x - dec, 1)[0])
+    return c.at[pos].set(_rows(dec, 1)[0]), res, planes
 
 
 def _ring_exchange(flat, axis_name: str, codec: SegmentCodec, key):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     m = flat.shape[0] // n
     fwd = [(i, (i + 1) % n) for i in range(n)]
     c, res, sent, key = _ring_rs(flat, axis_name, codec, key, n)
     key, sub = jax.random.split(key)
-    c, res, planes = _owner_encode(c, res, me, codec, sub)
+    c, res, planes = _owner_encode(c, res, me, m, codec, sub)
 
     def ag_step(i, carry):
         c, planes, sent = carry
@@ -214,21 +245,22 @@ def _ring_exchange(flat, axis_name: str, codec: SegmentCodec, key):
         # iterations are relays — n-1 sends total per plane
         sent = sent + codec.sent_elems(planes)
         planes = _permute(planes, axis_name, fwd)
-        c = c.at[(me - 1 - i) % n].set(codec.decode(planes)[:m])
+        c = c.at[(me - 1 - i) % n].set(_rows(codec.decode(planes)[:m],
+                                             1)[0])
         return c, planes, sent
 
     c, _, sent = lax.fori_loop(0, n - 1, ag_step, (c, planes, sent))
-    return c.reshape(-1), res.reshape(-1), sent
+    return _flat(c, m), _flat(res, m), sent
 
 
 def _butterfly_exchange(flat, axis_name: str, codec: SegmentCodec, key):
     """Halving-doubling: recursive-halving RS (hop EF) + an all-gather of
     the owner-encoded chunk planes (consistent decode everywhere)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0, "butterfly requires power-of-two workers"
     me = lax.axis_index(axis_name)
     m = flat.shape[0] // n
-    acc = flat.reshape(n, m)
+    acc = _rows(flat, n)
     res = jnp.zeros_like(acc)
     sent = jnp.zeros((), jnp.int32)
     levels = int(math.log2(n))
@@ -238,27 +270,27 @@ def _butterfly_exchange(flat, axis_name: str, codec: SegmentCodec, key):
         has_upper = (me & d) != 0
         my_start = base + jnp.where(has_upper, d, 0)
         send_start = base + jnp.where(has_upper, 0, d)
-        send = lax.dynamic_slice(acc, (send_start, 0), (d, m))
+        send = _take(acc, send_start, d, m)
         key, sub = jax.random.split(key)
-        planes, r = codec.encode_ef(send.reshape(-1), sub)
-        res_slice = lax.dynamic_slice(res, (send_start, 0), (d, m))
-        res = lax.dynamic_update_slice(res, res_slice + r.reshape(d, m),
-                                       (send_start, 0))
+        planes, r = codec.encode_ef(send, sub)
+        res_slice = lax.dynamic_slice_in_dim(res, send_start, d)
+        res = lax.dynamic_update_slice_in_dim(
+            res, res_slice + _rows(r, d), send_start, 0)
         sent = sent + codec.sent_elems(planes)
         planes = _permute(planes, axis_name, [(i, i ^ d) for i in range(n)])
-        recv = codec.decode(planes)[:d * m].reshape(d, m)
-        mine = lax.dynamic_slice(acc, (my_start, 0), (d, m))
-        acc = lax.dynamic_update_slice(acc, mine + recv, (my_start, 0))
+        recv = _rows(codec.decode(planes)[:d * m], d)
+        mine = lax.dynamic_slice_in_dim(acc, my_start, d)
+        acc = lax.dynamic_update_slice_in_dim(acc, mine + recv, my_start, 0)
     key, sub = jax.random.split(key)
-    acc, res, planes = _owner_encode(acc, res, me, codec, sub)
+    acc, res, planes = _owner_encode(acc, res, me, m, codec, sub)
     sent = sent + codec.sent_elems(planes) * (n - 1)   # AG transmissions
     gathered = jax.tree.map(lambda p: lax.all_gather(p, axis_name), planes)
     chunks = jax.vmap(codec.decode)(gathered)[:, :m]   # [n, m], identical
-    return chunks.reshape(-1), res.reshape(-1), sent
+    return chunks.reshape(-1), _flat(res, m), sent
 
 
 def _tree_exchange(flat, axis_name: str, codec: SegmentCodec, key):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     L = flat.shape[0]
     levels = int(math.log2(n))
@@ -297,7 +329,7 @@ def _tree_exchange(flat, axis_name: str, codec: SegmentCodec, key):
 
 def _fully_connected_exchange(flat, axis_name: str, codec: SegmentCodec,
                               key):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     L = flat.shape[0]
     key, sub = jax.random.split(key)
     planes, res = codec.encode_ef(flat, sub)
@@ -333,10 +365,11 @@ def compressed_reduce_scatter(flat, axis_name: str, codec: SegmentCodec,
     """Compressed ring reduce-scatter: rank r receives the reduced chunk
     r of ``flat`` (shape [len/n]).  Returns (my_shard_sum, residual,
     sent_elems) — the gradient-push half of the PS / ZeRO exchange."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
+    m = flat.shape[0] // n
     c, res, sent, _ = _ring_rs(flat, axis_name, codec, key, n)
-    return c[me], res.reshape(-1), sent
+    return _take(c, me, 1, m), _flat(res, m), sent
 
 
 def compressed_allreduce_ef(flat, ef, axis_name: str, topology: str,

@@ -1,7 +1,6 @@
 """The survey's contribution — its taxonomy of distributed-DL techniques —
 as first-class composable features:
 
-  collectives.py      —      version-portable shard_map shim
   parallelism.py      §3.2   data/tensor/hybrid sharding rules
   pipeline.py         §3.2.3 GPipe micro-batch pipeline
   parameter_server.py §3.3.1 centralized architecture (TPU adaptation)
@@ -12,9 +11,8 @@ as first-class composable features:
   comm_scheduler.py   §3.3.3 transfer scheduling (TicTac/Bosen model)
   precision.py        §3.3.3 mixed precision + stochastic rounding
 """
-from repro.core.collectives import shard_map
 from repro.core.compression import Compressor, METHODS
 from repro.core.sync import SimSyncEngine, SyncConfig, SyncEngine
 
 __all__ = ["Compressor", "METHODS", "SimSyncEngine", "SyncConfig",
-           "SyncEngine", "shard_map"]
+           "SyncEngine"]

@@ -30,7 +30,6 @@ from repro.comm.transport import (SCHEDULES, butterfly_allreduce,
                                   fully_connected_allreduce, per_device_bytes,
                                   psum_allreduce, ring_allreduce,
                                   tree_allreduce)
-from repro.core.collectives import axis_size
 
 TOPOLOGIES = SCHEDULES
 
@@ -49,7 +48,7 @@ def make_allreduce(topology: str, axis_name: str, mean: bool = True):
         def one(x):
             y = fn(x, axis_name)
             if mean:
-                y = y / axis_size(axis_name)
+                y = y / jax.lax.axis_size(axis_name)
             return y.astype(x.dtype)
         return jax.tree.map(one, tree)
 

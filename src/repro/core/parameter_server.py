@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.collectives import axis_size
 
 
 def _pad_to(x, n):
@@ -36,7 +35,7 @@ def _pad_to(x, n):
 
 def push_reduce_scatter(g, axis_name: str):
     """Gradient pytree -> my shard of the summed gradient (flat per leaf)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(x):
         flat, _ = _pad_to(x, n)
@@ -73,7 +72,7 @@ def make_ps_step(update_fn: Callable, axis_name: str):
     Returns ps_step(params, grads, opt_state) to be used inside shard_map:
     each worker plays parameter-server for its 1/n shard."""
     def ps_step(params, grads, opt_state):
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         g_shards = push_reduce_scatter(grads, axis_name)
         p_shards = jax.tree.map(
             lambda x: _shard_of(x, axis_name, n), params)
@@ -102,13 +101,13 @@ def pad_to_multiple(x, n: int):
 def shard_of_flat(x, axis_name: str):
     """My rank's 1/n shard of ``x`` (flattened, zero-padded) over
     ``axis_name`` — the PS "my parameters" view."""
-    return _shard_of(x, axis_name, axis_size(axis_name))
+    return _shard_of(x, axis_name, jax.lax.axis_size(axis_name))
 
 
 def reduce_scatter_flat(flat, axis_name: str):
     """Sum-reduce a (padded) flat vector over ``axis_name``, delivering
     each rank its own contiguous shard — the PS push."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return lax.psum_scatter(flat.reshape(n, -1), axis_name,
                             scatter_dimension=0, tiled=False)
 

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.collectives import axis_size
 
 
 def gpipe_forward(stage_fn: Callable, stage_params, x_micro, axis_name: str):
@@ -30,7 +29,7 @@ def gpipe_forward(stage_fn: Callable, stage_params, x_micro, axis_name: str):
     Returns [n_micro, mb, ...]: outputs (nonzero only on the last stage —
     psum over the axis to broadcast if needed).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     n_micro = x_micro.shape[0]
     mb_shape = x_micro.shape[1:]
@@ -93,7 +92,7 @@ def onefb_forward(stage_fn: Callable, stage_params, x_micro, axis_name: str,
     ``layers_local / v``) to x of shape [mb, ...].
     Returns [n_micro, mb, ...], nonzero only on the last stage device.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     v = int(interleave)
     n_micro = x_micro.shape[0]

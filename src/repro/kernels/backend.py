@@ -37,10 +37,7 @@ KERNEL_BACKENDS = ("auto", "kernel", "ref")
 @functools.lru_cache(maxsize=None)
 def _has_tpu() -> bool:
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - uninitialized backend
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve_backend(backend: str = "auto") -> str:

@@ -8,8 +8,8 @@ no repeated K/V ever materializes in VMEM.  Block shapes default to the
 MXU-aligned (128, 128); head_dim is the minor (lane) dimension.
 
 Causal / sliding-window masking is applied per-block from global positions.
-``interpret=True`` executes the kernel body on CPU (this container); on TPU
-hardware pass interpret=False.
+``interpret=True`` executes the kernel body on the CPU; on TPU hardware
+pass interpret=False.
 """
 from __future__ import annotations
 

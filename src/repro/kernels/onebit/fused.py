@@ -30,6 +30,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Mosaic's default scoped VMEM is 16 MiB.  The double-buffered blocks of
+# one grid step are held under this budget by taking fewer rows per step;
+# a row too wide for even one 8-row tile (an untied LM head's rows are
+# vocab-long) raises the kernel's VMEM limit instead.
+_VMEM_BLOCK_BUDGET = 12 << 20
+_VMEM_HEADROOM = 16 << 20
 
 
 def _kernel(*refs, gain: float, has_e: bool, has_valid: bool,
@@ -47,7 +55,10 @@ def _kernel(*refs, gain: float, has_e: bool, has_valid: bool,
         ctrue = g + e
     else:
         cin = ctrue = g
-    signs = jnp.where(cin >= 0, jnp.int8(1), jnp.int8(-1))
+    # The sign plane stays f32 inside the kernel and is narrowed to int8
+    # only at the store: Mosaic cannot relayout an i1 mask into int8
+    # tiling.  Same values as the oracle's int8 plane, so parity holds.
+    signs = jnp.where(cin >= 0, 1.0, -1.0)
     valid = (v_ref[...] != 0) if has_valid else None
 
     if symmetric:
@@ -65,7 +76,7 @@ def _kernel(*refs, gain: float, has_e: bool, has_valid: bool,
 
     recon = jnp.where(signs > 0, sp, -sn)
     out = recon if valid is None else jnp.where(valid, recon, 0.0)
-    s_ref[...] = signs
+    s_ref[...] = signs.astype(jnp.int8)
     sp_ref[...] = sp
     sn_ref[...] = sn
     o_ref[...] = out
@@ -82,7 +93,12 @@ def onebit_encode_ef(g, e=None, valid=None, *, gain: float = 1.0,
     means no error feedback (``c_in = c_true = g``, the segment-codec
     case); ``valid=None`` means every element is real."""
     R, C = g.shape
-    br = min(block_r, R)
+    # bytes of one row across every block: f32 g (and e), int8 valid,
+    # int8 signs, f32 out and new_e, and the lane-padded sp/sn columns
+    row_bytes = (C * (4 + 4 * (e is not None) + (valid is not None)
+                      + 1 + 4 + 4) + 2 * 4 * 128)
+    fit = max(8, _VMEM_BLOCK_BUDGET // (2 * row_bytes) // 8 * 8)
+    br = min(block_r, R, fit)
     r_pad = (R + br - 1) // br * br
 
     def rpad(x, fill=0):
@@ -107,6 +123,8 @@ def onebit_encode_ef(g, e=None, valid=None, *, gain: float = 1.0,
                    jax.ShapeDtypeStruct((r_pad, 1), jnp.float32),
                    jax.ShapeDtypeStruct((r_pad, C), jnp.float32),
                    jax.ShapeDtypeStruct((r_pad, C), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * br * row_bytes + _VMEM_HEADROOM),
         interpret=interpret,
     )(*operands)
     return signs[:R], sp[:R], sn[:R], out[:R], new_e[:R]

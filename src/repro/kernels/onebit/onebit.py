@@ -22,11 +22,13 @@ from jax.experimental import pallas as pl
 
 def _kernel(g_ref, e_ref, s_ref, scale_ref, ne_ref):
     c = g_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
-    signs = jnp.where(c >= 0, jnp.int8(1), jnp.int8(-1))
+    # f32 sign plane, narrowed to int8 only at the store (an i1 -> int8
+    # select does not lower on Mosaic).
+    signs = jnp.where(c >= 0, 1.0, -1.0)
     scale = jnp.mean(jnp.abs(c), axis=-1, keepdims=True)
-    s_ref[...] = signs
+    s_ref[...] = signs.astype(jnp.int8)
     scale_ref[...] = scale
-    ne_ref[...] = c - signs.astype(jnp.float32) * scale
+    ne_ref[...] = c - signs * scale
 
 
 def onebit_compress(g, e, *, block_r: int = 256, interpret: bool = True):

@@ -20,8 +20,10 @@ def _kernel(g_ref, u_ref, stats_ref, t_ref):
     g = jnp.where(sigma_clip > 0,
                   jnp.clip(g, -sigma_clip, sigma_clip), g)
     p = jnp.abs(g) / jnp.maximum(s, 1e-30)
-    b = (u_ref[...] < p).astype(jnp.int8)
-    t_ref[...] = jnp.sign(g).astype(jnp.int8) * b
+    # Product in f32 ({-1, 0, 1} exactly), narrowed to int8 at the store:
+    # Mosaic has no int8 multiply.
+    b = (u_ref[...] < p).astype(jnp.float32)
+    t_ref[...] = (jnp.sign(g) * b).astype(jnp.int8)
 
 
 def terngrad_ternarize(gc, u, s, *, block_r: int = 256,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.env import use_compile_cache
 from repro.models import build_model
 from repro.serve.autoscale import poisson_trace
 from repro.serve.batcher import POLICIES
@@ -66,6 +67,7 @@ def main():
                          "(repeatable); burning SLOs emit slo_burn "
                          "instants (docs/serving.md)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -1,11 +1,12 @@
 """Training launcher.
 
-Small-scale real run (this host):
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
       --smoke --steps 20
-lowers + executes a reduced config on the host devices; the production
-mesh path is exercised via `repro.launch.dryrun` (no TPU in this
-container).
+
+runs a reduced config on the process's default device: a TPU chip where
+JAX finds one, the CPU under ``JAX_PLATFORMS=cpu`` (Pallas kernels then
+run in interpret mode).  ``chip_smoke.py`` at the repo root drives the
+Strategy trainer and the serving engine at published widths on a chip.
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ from repro.configs import get_config
 from repro.core.compression import Compressor
 from repro.core.precision import PrecisionPolicy
 from repro.data import LMDataConfig, make_lm_batches
+from repro.launch.env import use_compile_cache
 from repro.models import build_model
 from repro.optim import OPTIMIZERS
 from repro.optim.schedule import cosine_warmup
@@ -48,6 +50,7 @@ def main():
                          "attribution etc.) after the run; implies "
                          "tracing even without --trace")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

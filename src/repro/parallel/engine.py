@@ -46,7 +46,6 @@ from repro.comm.plan import CommPlan, modeled_event_bytes
 from repro.comm.transport import (compressed_allreduce,
                                   compressed_reduce_scatter,
                                   schedule_tx_bytes)
-from repro.core.collectives import shard_map
 from repro.core.compression import Compressor, EF_METHODS
 from repro.core.parameter_server import shard_of_flat
 from repro.core.pipeline import (bubble_fraction, gpipe_forward, gpipe_ticks,
@@ -728,7 +727,7 @@ class HybridEngine:
             return p_out, opt_new, ef_out, loss[None], expand3(sent)
 
         params_spec, opt_spec, ef_spec = self._state_specs()
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(params_spec, opt_spec, ef_spec, P(DATA), P()),
             out_specs=(params_spec, opt_spec, ef_spec, P(DATA),
@@ -902,7 +901,7 @@ class HybridEngine:
             return loss[None], g, ef_out
 
         ef_spec = P(DATA) if self._ef_active else P()
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             grad_body, mesh=self.mesh,
             in_specs=(P(DATA), ef_spec, P(DATA), P(DATA), P(DATA)),
             out_specs=(P(DATA), P(DATA), ef_spec),
@@ -941,7 +940,7 @@ class HybridEngine:
                 r, center, g)
             return (jax.tree.map(lambda x: x[None], new_r), loss[None])
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             sma_body, mesh=self.mesh,
             in_specs=(P(DATA), P(DATA)),
             out_specs=(P(DATA), P(DATA)),

@@ -12,10 +12,10 @@ can place the two collectives of the column→row parallel pair:
     — ``tensor_copy`` is the identity-forward / psum-backward operator
     (Megatron's conjugate "g" to the forward "f" = ``tensor_reduce``).
 
-Both are ``custom_vjp``-wrapped: under ``shard_map(check_rep=False)``
-(the only mode jax 0.4.37 supports for these programs) a raw ``lax.psum``
-transposes to another psum — pmap semantics — which over-counts the
-cotangent by the axis size.  The custom rules pin the correct transposes
+Both are ``custom_vjp``-wrapped: under ``shard_map(check_vma=False)``
+(the mode these programs run in) a raw ``lax.psum`` transposes to
+another psum — pmap semantics — which over-counts the cotangent by the
+axis size.  The custom rules pin the correct transposes
 (psum ↔ identity), which is exactly Megatron's f/g conjugate pair.
 
 ``StagedModel`` is the contract the hybrid engine consumes; the tiny

@@ -94,6 +94,13 @@ class ServeEngine:
         self.kv = make_kv_store(
             model, scfg.slots, scfg.max_len, scfg.page_size, scfg.num_pages,
             dtype=scfg.cache_dtype, window_override=scfg.window_override)
+        # the decode step's params: under tp, sharded over the mesh once
+        # here (prefill keeps ``params``: its Pallas kernels run on one
+        # device)
+        self._step_params = params
+        if self._tp is not None:
+            self._step_params, self.kv.store = self._tp.place(
+                params, self.kv.store)
         self.batcher = Batcher(self.kv, scfg.slots, scfg.policy)
 
         self.requests: List[Request] = []
@@ -151,7 +158,8 @@ class ServeEngine:
 
         if self._tp is None:
             return jax.jit(step)
-        return jax.jit(self._tp.wrap_step(step, self.params, self.kv.store))
+        return jax.jit(self._tp.wrap_step(step, self._step_params,
+                                          self.kv.store))
 
     # --------------------------------------------------------- lifecycle
     def submit(self, request: Request) -> None:
@@ -215,6 +223,8 @@ class ServeEngine:
             conv = self.model.cache_from_prefill(
                 states, self.scfg.max_len, dtype=self.scfg.cache_dtype,
                 window_override=self.scfg.window_override)
+            if self._tp is not None:
+                conv = self._tp.replicate(conv)
             for j, r in enumerate(rs):
                 self.kv.write_prefill(r.slot, conv, j, plen)
 
@@ -248,6 +258,20 @@ class ServeEngine:
                 if len(r.output) >= r.max_new_tokens:
                     self._finish(r)
 
+    def _step_args(self, pos, active, tok_idx):
+        return (self._step_params, self.kv.store,
+                self.kv.block_tables_device(), jnp.asarray(self._last_tok),
+                jnp.asarray(pos), jnp.asarray(active),
+                jnp.asarray(self._seeds), jnp.asarray(tok_idx),
+                jnp.asarray(self._temp), jnp.asarray(self._topk))
+
+    def lower_step(self):
+        """The jitted decode step lowered at this engine's shapes (read
+        the program it compiles to with ``.compile().as_text()``)."""
+        idle = np.zeros(self.scfg.slots, np.int32)
+        return self._step.lower(*self._step_args(
+            idle, np.zeros(self.scfg.slots, bool), idle))
+
     def _decode_iteration(self) -> None:
         B = self.scfg.slots
         pos = np.zeros(B, np.int32)
@@ -260,12 +284,7 @@ class ServeEngine:
                 pos[i] = r.prompt_len + len(r.output) - 1
                 tok_idx[i] = len(r.output)
                 decoding.append(r)
-        nxt, new_store = self._step(
-            self.params, self.kv.store, self.kv.block_tables_device(),
-            jnp.asarray(self._last_tok), jnp.asarray(pos),
-            jnp.asarray(active), jnp.asarray(self._seeds),
-            jnp.asarray(tok_idx), jnp.asarray(self._temp),
-            jnp.asarray(self._topk))
+        nxt, new_store = self._step(*self._step_args(pos, active, tok_idx))
         self.kv.store = new_store
         self.clock += 1.0
         self.decode_iterations += 1

@@ -27,10 +27,9 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.collectives import shard_map
 
 _COL = frozenset({"wq", "wk", "wv", "w_gate", "w_up"})
 _ROW = frozenset({"wo", "w_down"})
@@ -93,6 +92,21 @@ class TPContext:
             cfg, num_heads=cfg.num_heads // tp,
             num_kv_heads=cfg.num_kv_heads // tp)
 
+    def place(self, params, store):
+        """Params and KV store laid out on the mesh as the step's
+        ``shard_map`` reads them.  Done once, at engine construction, so
+        no step call re-shards the model."""
+        def put(tree, specs):
+            return jax.device_put(tree, jax.tree.map(
+                lambda s: NamedSharding(self.mesh, s), specs,
+                is_leaf=lambda x: isinstance(x, P)))
+        return (put(params, param_specs(params)),
+                put(store, store_specs(store)))
+
+    def replicate(self, tree):
+        """``tree`` copied to every device of the mesh."""
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
+
     def wrap_step(self, step_fn, params, store):
         """shard_map the engine's step(params, store, bt, tokens, pos,
         active, seeds, tok_idx, temp, topk) -> (next_tokens, new_store).
@@ -102,5 +116,5 @@ class TPContext:
         ss = store_specs(store)
         in_specs = (param_specs(params), ss) + (P(),) * 8
         out_specs = (P(), ss)
-        return shard_map(step_fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(step_fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)

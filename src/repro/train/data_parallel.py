@@ -67,7 +67,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.comm.codecs import SPARSE_ELEM_BYTES
 from repro.comm.plan import CommPlan, plan_buckets, scatter_flat
-from repro.core.collectives import axis_size, shard_map
 from repro.core.comm_scheduler import LayerCost, LinkModel
 from repro.core.compression import Compressor, EF_METHODS
 from repro.core.parameter_server import make_ps_step, sgd_update_fn
@@ -152,7 +151,7 @@ def make_bucketed_ps_update(params_example, lr: float,
                    for x in jax.tree.leaves(params_example)]
 
     def ps_update(params, grads):
-        n = axis_size(axis)
+        n = jax.lax.axis_size(axis)
         p_leaves = jax.tree.leaves(params)
         g_leaves = jax.tree.leaves(grads)
         # lists, NOT dicts: jax flattens dict keys in sorted order, which
@@ -203,10 +202,10 @@ def make_sharded_train_step(train_step: Callable, mesh: Mesh,
     ef_spec = P(AXIS) if compressed else P()
     state_spec = {"params": P(), "opt_state": P(), "step": P(),
                   "ef": ef_spec}
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(state_spec, P(AXIS), P()),
-                   out_specs=(state_spec, P()),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(state_spec, P(AXIS), P()),
+                       out_specs=(state_spec, P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -325,6 +324,19 @@ class DeviceEngine(ElasticWorkerSet):
             lambda p, g, lr: jax.tree.map(lambda a, b: a - lr * b, p, g))
 
     @property
+    def comm_plan(self) -> Optional[CommPlan]:
+        """The exchange plan the engine executes (None until a step or a
+        wire query builds it)."""
+        return self._plan
+
+    @property
+    def bsp_step(self):
+        """The jitted BSP step ``(params, ef, batch, rngs, weights) ->
+        (params, ef, losses, sent)``, every argument but ``params`` with a
+        leading worker axis (None until the first BSP step)."""
+        return self._step_fn
+
+    @property
     def _ef_active(self) -> bool:
         return self.cfg.compressor.method in EF_METHODS
 
@@ -424,10 +436,10 @@ class DeviceEngine(ElasticWorkerSet):
             return (new_params, ef_out, loss[None], sent[None])
 
         ef_spec = P(AXIS) if self._ef_active else P()
-        fn = shard_map(sharded_step, mesh=self.mesh,
-                       in_specs=(P(), ef_spec, P(AXIS), P(AXIS), P(AXIS)),
-                       out_specs=(P(), ef_spec, P(AXIS), P(AXIS)),
-                       check_vma=False)
+        fn = jax.shard_map(sharded_step, mesh=self.mesh,
+                           in_specs=(P(), ef_spec, P(AXIS), P(AXIS), P(AXIS)),
+                           out_specs=(P(), ef_spec, P(AXIS), P(AXIS)),
+                           check_vma=False)
         return jax.jit(fn)
 
     def _event_wire_bytes(self, params) -> int:
@@ -516,10 +528,10 @@ class DeviceEngine(ElasticWorkerSet):
                 r, center, g)
             return (jax.tree.map(lambda x: x[None], new_r), loss[None])
 
-        fn = shard_map(sma_body, mesh=self.mesh,
-                       in_specs=(P(AXIS), P(AXIS)),
-                       out_specs=(P(AXIS), P(AXIS)),
-                       check_vma=False)
+        fn = jax.shard_map(sma_body, mesh=self.mesh,
+                           in_specs=(P(AXIS), P(AXIS)),
+                           out_specs=(P(AXIS), P(AXIS)),
+                           check_vma=False)
         return jax.jit(fn)
 
     def _param_bytes(self, params_like) -> int:
@@ -576,7 +588,7 @@ class DeviceEngine(ElasticWorkerSet):
             return loss[None], g, ef_out
 
         ef_spec = P(AXIS) if self._ef_active else P()
-        grad_fn = jax.jit(shard_map(
+        grad_fn = jax.jit(jax.shard_map(
             grad_body, mesh=self.mesh,
             in_specs=(P(AXIS), ef_spec, P(AXIS), P(AXIS), P(AXIS)),
             out_specs=(P(AXIS), P(AXIS), ef_spec),
@@ -597,7 +609,7 @@ class DeviceEngine(ElasticWorkerSet):
                 new_params, _ = step(params, contrib, None)
                 return new_params
 
-            ps_apply = jax.jit(shard_map(
+            ps_apply = jax.jit(jax.shard_map(
                 ps_body, mesh=self.mesh,
                 in_specs=(P(), P(AXIS), P(AXIS)),
                 out_specs=P(),

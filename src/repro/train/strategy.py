@@ -624,12 +624,14 @@ class Trainer:
     spec string like ``"crash:w1@5,resize:4@10"``) routes the run through
     the elastic trainer: the engine is periodically snapshotted through
     ``repro.checkpoint`` and survives crashes, resizes, restarts, and
-    straggler events without restarting the process (docs/elasticity.md)."""
+    straggler events without restarting the process (docs/elasticity.md).
+    Without a plan, ``engine`` is the engine the last ``fit`` built."""
 
     def __init__(self, strategy: Strategy,
                  devices: Optional[Sequence] = None):
         self.strategy = strategy
         self.devices = devices
+        self.engine: Optional[Engine] = None
 
     def fit(self, grad_fn: Callable, params,
             batches: Callable[[int, int], Any], steps: int, *,
@@ -641,5 +643,5 @@ class Trainer:
                                steps, plan, checkpoint_dir=checkpoint_dir,
                                checkpoint_every=checkpoint_every,
                                devices=self.devices)
-        engine = self.strategy.build(grad_fn, self.devices)
-        return fit(engine, params, batches, steps)
+        self.engine = self.strategy.build(grad_fn, self.devices)
+        return fit(self.engine, params, batches, steps)

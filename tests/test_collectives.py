@@ -6,22 +6,21 @@ SCRIPT_TOPO = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.allreduce import TOPOLOGIES
-from repro.core.collectives import shard_map
 mesh = Mesh(np.array(jax.devices()).reshape(8), ("w",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 40))
 for name, fn in TOPOLOGIES.items():
-    f = shard_map(lambda a, _fn=fn: _fn(a[0], "w")[None], mesh=mesh,
-                  in_specs=P("w", None), out_specs=P("w", None),
-                  check_vma=False)
+    f = jax.shard_map(lambda a, _fn=fn: _fn(a[0], "w")[None], mesh=mesh,
+                      in_specs=P("w", None), out_specs=P("w", None),
+                      check_vma=False)
     out = f(x)
     expect = jnp.broadcast_to(x.sum(0)[None], (8, 40))
     err = float(jnp.max(jnp.abs(out - expect)))
     assert err < 1e-4, (name, err)
 # odd-size tensor through ring (padding path)
 y = jax.random.normal(jax.random.PRNGKey(1), (8, 37))
-f = shard_map(lambda a: TOPOLOGIES["ring"](a[0], "w")[None], mesh=mesh,
-              in_specs=P("w", None), out_specs=P("w", None),
-              check_vma=False)
+f = jax.shard_map(lambda a: TOPOLOGIES["ring"](a[0], "w")[None], mesh=mesh,
+                  in_specs=P("w", None), out_specs=P("w", None),
+                  check_vma=False)
 err = float(jnp.max(jnp.abs(f(y) - y.sum(0)[None])))
 assert err < 1e-4, err
 print("TOPO-OK")
@@ -31,7 +30,6 @@ SCRIPT_PS = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.parameter_server import make_ps_step
-from repro.core.collectives import shard_map
 mesh = Mesh(np.array(jax.devices()).reshape(8), ("w",))
 def update(p_sh, g_sh, opt):
     return jax.tree.map(lambda a, b: a - 0.1 * b, p_sh, g_sh), opt
@@ -39,9 +37,9 @@ ps = make_ps_step(update, "w")
 pp = {"W": jax.random.normal(jax.random.PRNGKey(0), (13, 3)),
       "b": jnp.ones((5,))}
 gg = jax.tree.map(lambda x: jnp.stack([x * 0 + i for i in range(8)]), pp)
-f = shard_map(lambda p, g: ps(p, jax.tree.map(lambda a: a[0], g), None)[0],
-              mesh=mesh, in_specs=(P(), P("w")), out_specs=P(),
-              check_vma=False)
+f = jax.shard_map(lambda p, g: ps(p, jax.tree.map(lambda a: a[0], g), None)[0],
+                  mesh=mesh, in_specs=(P(), P("w")), out_specs=P(),
+                  check_vma=False)
 newp = f(pp, gg)
 expect = jax.tree.map(lambda x: x - 0.1 * sum(range(8)), pp)
 for a, b in zip(jax.tree.leaves(newp), jax.tree.leaves(expect)):
@@ -53,15 +51,14 @@ SCRIPT_PIPE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.pipeline import gpipe_forward, bubble_fraction
-from repro.core.collectives import shard_map
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("stage", "data"))
 stacked = jnp.stack([jnp.eye(6) * (i + 1) + 0.01 * i for i in range(4)])
 xm = jax.random.normal(jax.random.PRNGKey(0), (8, 2, 6))
 def stage_fn(w, x):
     return jnp.tanh(x @ w)
-f = shard_map(lambda w, x: gpipe_forward(stage_fn, w[0], x, "stage")[None],
-              mesh=mesh, in_specs=(P("stage"), P(None)),
-              out_specs=P("stage"), check_vma=False)
+f = jax.shard_map(lambda w, x: gpipe_forward(stage_fn, w[0], x, "stage")[None],
+                  mesh=mesh, in_specs=(P("stage"), P(None)),
+                  out_specs=P("stage"), check_vma=False)
 out = f(stacked, xm).sum(0)      # only last stage nonzero
 seq = xm
 for i in range(4):

@@ -113,7 +113,6 @@ def test_model_error_factor_is_exact_for_none():
 SCRIPT_SCHEDULES = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.collectives import shard_map
 from repro.comm.codecs import make_codec
 from repro.comm.transport import compressed_allreduce, pad_for_schedule
 
@@ -129,7 +128,7 @@ for topo in ("ring", "tree", "butterfly", "fully_connected"):
             flat = jnp.pad(xx[0], (0, Pl - L))
             red, res, sent = compressed_allreduce(flat, "w", topo, codec, kk[0])
             return red[None, :L], res[None, :L], sent[None]
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("w"), P("w")),
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("w"), P("w")),
                     out_specs=(P("w"), P("w"), P("w")), check_vma=False))
         red, res, sent = f(x, jax.random.split(jax.random.PRNGKey(1), n))
         red, res = np.asarray(red), np.asarray(res)

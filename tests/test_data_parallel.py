@@ -1,61 +1,26 @@
-"""DataParallelEngine + collectives shim tests (PR 1 tentpole).
+"""DataParallelEngine tests (PR 1 tentpole).
 
-Covers: shim resolution on both jax layouts, kwarg translation,
-engine-vs-simulator equivalence on 8 virtual devices, kernel-vs-ref
-bit-identity through the sharded compressed path, EF state round-trip,
-wire accounting, and the TicTac bucket-order timeline model.
+Covers: a real ``jax.shard_map`` on one device, engine-vs-simulator
+equivalence on 8 virtual devices, kernel-vs-ref bit-identity through the
+sharded compressed path, EF state round-trip, wire accounting, and the
+TicTac bucket-order timeline model.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import collectives
 from repro.core.comm_scheduler import LinkModel
 
 
-# ----------------------------------------------------------------- shim unit
-def test_shim_resolves_on_installed_jax():
-    fn, origin = collectives.resolve_shard_map()
-    assert callable(fn)
-    assert origin in ("jax.shard_map", "jax.experimental.shard_map.shard_map")
-
-
-def test_shim_translates_check_vma_to_old_layout(monkeypatch):
-    """A jax exposing only the old check_rep kwarg must receive check_rep."""
-    seen = {}
-
-    def old_style(f, mesh=None, in_specs=None, out_specs=None,
-                  check_rep=True):
-        seen.update(check_rep=check_rep)
-        return f
-    monkeypatch.setattr(jax, "shard_map", old_style, raising=False)
-    collectives.shard_map(lambda x: x, mesh="m", in_specs=(), out_specs=(),
-                          check_vma=False)
-    assert seen == {"check_rep": False}
-
-
-def test_shim_translates_to_new_layout(monkeypatch):
-    """A jax exposing the promoted jax.shard_map with check_vma gets it
-    verbatim, whether the caller wrote check_vma or legacy check_rep."""
-    seen = {}
-
-    def new_style(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=True):
-        seen.update(check_vma=check_vma)
-        return f
-    monkeypatch.setattr(jax, "shard_map", new_style, raising=False)
-    collectives.shard_map(lambda x: x, mesh="m", in_specs=(), out_specs=(),
-                          check_rep=False)
-    assert seen == {"check_vma": False}
-
-
-def test_shim_runs_a_real_shard_map():
-    """End-to-end through whatever layout this jax has (single device)."""
+# ------------------------------------------------------------ shard_map
+def test_jax_shard_map_runs():
+    """``jax.shard_map`` with ``jax.lax.axis_size`` end to end (one
+    device)."""
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("w",))
-    f = collectives.shard_map(
-        lambda x: x * collectives.axis_size("w"), mesh=mesh,
+    f = jax.shard_map(
+        lambda x: x * jax.lax.axis_size("w"), mesh=mesh,
         in_specs=P("w"), out_specs=P("w"), check_vma=False)
     out = f(jnp.arange(4.0))
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0))

@@ -50,7 +50,6 @@ def test_onefb_tick_and_bubble_accounting():
 SCRIPT_GRADS = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.collectives import shard_map
 from repro.core.pipeline import (bubble_fraction, gpipe_forward,
                                  gpipe_ticks, stacked_forward)
 from repro.parallel import make_tiny_transformer
@@ -87,8 +86,8 @@ def run_case(n_stages, n_micro, mb):
             return tensor_reduce("stage")(l)
         return jax.value_and_grad(loss_fn)(sp)
     spec = jax.tree.map(lambda _: P("stage"), params)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,),
-                   out_specs=(P(), spec), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                       out_specs=(P(), spec), check_vma=False)
     l_pipe, g_pipe = jax.jit(fn)(params)
 
     ld = abs(float(l_ref) - float(l_pipe))
@@ -122,7 +121,6 @@ def test_gpipe_grads_match_stacked_model(multidevice):
 SCRIPT_ONEFB = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.collectives import shard_map
 from repro.core.pipeline import onefb_forward, stacked_forward
 from repro.parallel.staged import tensor_reduce
 
@@ -163,8 +161,8 @@ def run_case(n_stages, v, n_micro, layers_per_stage, mb=2):
             return tensor_reduce("stage")(l)
         return jax.value_and_grad(loss_fn)(p)
     spec = {"W": P("stage")}
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,),
-                   out_specs=(P(), spec), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                       out_specs=(P(), spec), check_vma=False)
     l_pipe, g_pipe = jax.jit(fn)({"W": W[perm]})
     g_pipe = np.asarray(g_pipe["W"])[np.argsort(perm)]
 

@@ -63,7 +63,7 @@ P0 = {"W": jnp.zeros((64, 1)), "b": jnp.zeros((4096,))}
 def check_measured_vs_model(engine, topology, method) -> None:
     """The engine's static per-worker tx bytes must match the topology's
     critical-path model through the documented error factor."""
-    plan = engine.inner._plan
+    plan = engine.inner.comm_plan
     codec = (make_codec("none") if method in ("none",)
              else plan.codec)
     expect = 0.0
