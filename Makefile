@@ -8,8 +8,7 @@ PYTEST := env PYTHONPATH=src timeout
 SMOKE_TIMEOUT ?= 300
 TIER1_TIMEOUT ?= 900
 
-.PHONY: smoke tier1 bench strategies elastic hybrid comm kernels serve obs \
-	bench-regress
+.PHONY: smoke tier1 bench strategies elastic hybrid comm kernels serve obs
 
 # Fast subset: pure-host unit tests (collectives shim units, compression,
 # schedulers, configs, models). ~1 min.
@@ -67,19 +66,12 @@ serve:
 obs:
 	$(PYTEST) $(SMOKE_TIMEOUT) python tools/obs_smoke.py
 
-# Bench-lineage gate: the newest committed BENCH_pr<N>.json vs its
-# predecessors on the keyed deterministic metrics (wire bytes, seeded
-# loss bands, modeled times, virtual-clock latencies); see
-# docs/observability.md "Analysis & SLOs".
-bench-regress:
-	$(PYTEST) $(SMOKE_TIMEOUT) python tools/bench_regress.py
-
 # Full tier-1 verify (ROADMAP.md): the strategy-matrix, elasticity,
-# hybrid-mesh, comm-plane, kernel-backend, serving, observability, and
-# bench-lineage gates plus everything in tests/, including the
+# hybrid-mesh, comm-plane, kernel-backend, serving and observability
+# gates plus everything in tests/, including the
 # 8-virtual-device subprocess tests and end-to-end training
 # compositions.
-tier1: strategies elastic hybrid comm kernels serve obs bench-regress
+tier1: strategies elastic hybrid comm kernels serve obs
 	$(PYTEST) $(TIER1_TIMEOUT) python -m pytest -q
 
 bench:

@@ -1,0 +1,8 @@
+"""Device self time per step of the step program's backward ops
+(``scopes.classify``), averaged over the chips used."""
+from chip import scopes
+
+
+def read(run):
+    sc = scopes.of(run)
+    return sc.class_ms("backward") if sc else None

@@ -1,0 +1,8 @@
+"""Device self time per step of the step program's forward ops
+(``scopes.classify``), averaged over the chips used."""
+from chip import scopes
+
+
+def read(run):
+    sc = scopes.of(run)
+    return sc.class_ms("forward") if sc else None

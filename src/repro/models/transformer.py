@@ -90,15 +90,19 @@ def _layer_forward(p, cfg: ModelConfig, sig, x, positions, state=None,
     state is only used/returned for stateful kinds (cache build in prefill)."""
     kind, use_moe = sig
     aux = jnp.float32(0.0)
-    h = norm_apply(cfg.norm, p["ln1"], x, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = norm_apply(cfg.norm, p["ln1"], x, cfg.norm_eps)
     new_state = None
     if kind in ("attn", "local"):
-        if cfg.attn_type == "mla":
-            out, new_state = mla_mod.mla_forward(p["mixer"], h, positions, cfg)
-        else:
-            window = cfg.window if kind == "local" else window_override
-            out, new_state = attn.attention_forward(
-                p["mixer"], h, positions, cfg, causal=True, window=window)
+        with jax.named_scope("attention"):
+            if cfg.attn_type == "mla":
+                out, new_state = mla_mod.mla_forward(p["mixer"], h,
+                                                     positions, cfg)
+            else:
+                window = cfg.window if kind == "local" else window_override
+                out, new_state = attn.attention_forward(
+                    p["mixer"], h, positions, cfg, causal=True,
+                    window=window)
     elif kind == "rglru":
         out, (h_last, conv_buf) = rglru_mod.rglru_forward(p["mixer"], h)
         new_state = {"h": h_last, "conv": conv_buf}
@@ -111,11 +115,13 @@ def _layer_forward(p, cfg: ModelConfig, sig, x, positions, state=None,
                      "shift_cm": shift_cm}
         return x + out2, aux, new_state
     x = x + out
-    h = norm_apply(cfg.norm, p["ln2"], x, cfg.norm_eps)
-    if use_moe:
-        out, aux = moe_apply(p["moe"], h, cfg)
-    else:
-        out = mlp_apply(p["mlp"], h, cfg.act)
+    with jax.named_scope("norm"):
+        h = norm_apply(cfg.norm, p["ln2"], x, cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if use_moe:
+            out, aux = moe_apply(p["moe"], h, cfg)
+        else:
+            out = mlp_apply(p["mlp"], h, cfg.act)
     return x + out, aux, new_state
 
 
@@ -142,25 +148,30 @@ def _layer_decode(p, cfg: ModelConfig, sig, x, pos, cache, window_override=0,
     if kind == "rwkv":
         return rwkv_mod.rwkv_block_decode(
             p["mixer"], p["mixer"], p["ln1"], p["ln2"], cfg, x, cache)
-    h = norm_apply(cfg.norm, p["ln1"], x, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = norm_apply(cfg.norm, p["ln1"], x, cfg.norm_eps)
     if kind in ("attn", "local"):
-        if cfg.attn_type == "mla":
-            out, new_cache = mla_mod.mla_decode(p["mixer"], h, pos, cache, cfg)
-        else:
-            window = cfg.window if kind == "local" else window_override
-            out, new_cache = attn.attention_decode(
-                p["mixer"], t_copy(h), pos, cache, cfg, window=window)
-            out = t_reduce(out)
+        with jax.named_scope("attention"):
+            if cfg.attn_type == "mla":
+                out, new_cache = mla_mod.mla_decode(p["mixer"], h, pos,
+                                                    cache, cfg)
+            else:
+                window = cfg.window if kind == "local" else window_override
+                out, new_cache = attn.attention_decode(
+                    p["mixer"], t_copy(h), pos, cache, cfg, window=window)
+                out = t_reduce(out)
     elif kind == "rglru":
         out, new_cache = rglru_mod.rglru_decode(p["mixer"], h, cache)
     else:
         raise ValueError(kind)
     x = x + out
-    h = norm_apply(cfg.norm, p["ln2"], x, cfg.norm_eps)
-    if use_moe:
-        out, _ = moe_apply(p["moe"], h, cfg)
-    else:
-        out = t_reduce(mlp_apply(p["mlp"], t_copy(h), cfg.act))
+    with jax.named_scope("norm"):
+        h = norm_apply(cfg.norm, p["ln2"], x, cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if use_moe:
+            out, _ = moe_apply(p["moe"], h, cfg)
+        else:
+            out = t_reduce(mlp_apply(p["mlp"], t_copy(h), cfg.act))
     return x + out, new_cache
 
 
@@ -226,10 +237,11 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
     """
     B, S = tokens.shape
     segs = plan_segments(cfg)
-    x = params["embed"].astype(compute_dtype)[tokens]
-    if vision_embeds is not None:
-        x = jax.lax.dynamic_update_slice(
-            x, vision_embeds.astype(compute_dtype), (0, 0, 0))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(compute_dtype)[tokens]
+        if vision_embeds is not None:
+            x = jax.lax.dynamic_update_slice(
+                x, vision_embeds.astype(compute_dtype), (0, 0, 0))
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         if cfg.mrope_sections:
@@ -280,12 +292,18 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
                     body, (x, aux_total), p_seg)
             if return_cache:
                 caches.append(seg_states)
-    x = norm_apply(cfg.norm, params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].astype(compute_dtype).T
-    else:
-        logits = dense({"w": params["lm_head"]}, x)
+    logits = _head(params, cfg, x, compute_dtype)
     return logits, aux_total, (caches if return_cache else None)
+
+
+def _head(params, cfg: ModelConfig, x, compute_dtype):
+    """Final norm and the (tied or untied) vocabulary projection."""
+    with jax.named_scope("norm"):
+        x = norm_apply(cfg.norm, params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            return x @ params["embed"].astype(compute_dtype).T
+        return dense({"w": params["lm_head"]}, x)
 
 
 def loss_fn(params, cfg: ModelConfig, batch, compute_dtype=jnp.bfloat16,
@@ -296,8 +314,9 @@ def loss_fn(params, cfg: ModelConfig, batch, compute_dtype=jnp.bfloat16,
         params, cfg, batch["tokens"], positions=batch.get("positions"),
         vision_embeds=batch.get("vision_embeds"), compute_dtype=compute_dtype,
         remat=remat, unroll=unroll)
-    ce = cross_entropy(logits, batch["labels"], batch.get("mask"),
-                       vocab_size=cfg.vocab_size)
+    with jax.named_scope("loss"):
+        ce = cross_entropy(logits, batch["labels"], batch.get("mask"),
+                           vocab_size=cfg.vocab_size)
     return ce + aux, {"ce": ce, "aux": aux}
 
 
@@ -335,7 +354,8 @@ def decode_step(params, cfg: ModelConfig, caches, token, pos,
     ``tensor_reduce`` before the residual adds — Megatron's f/g pair from
     ``repro.parallel.staged``, reused for inference."""
     segs = plan_segments(cfg)
-    x = params["embed"].astype(compute_dtype)[token]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(compute_dtype)[token]
     new_caches: List[Any] = []
     for seg, p_seg, c_seg in zip(segs, params["segments"], caches):
         if seg[0] == "plain":
@@ -367,12 +387,7 @@ def decode_step(params, cfg: ModelConfig, caches, token, pos,
             else:
                 x, seg_caches = jax.lax.scan(body, x, (p_seg, c_seg))
             new_caches.append(seg_caches)
-    x = norm_apply(cfg.norm, params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].astype(compute_dtype).T
-    else:
-        logits = dense({"w": params["lm_head"]}, x)
-    return logits, new_caches
+    return _head(params, cfg, x, compute_dtype), new_caches
 
 
 def prefill(params, cfg: ModelConfig, tokens, positions=None,

@@ -21,9 +21,6 @@ accounting, serve latency extraction, and the
 ``repro.obs.slo`` — declarative serve objectives (``ttft_p99<8``) with
 multi-window burn-rate alerting, wired into the serve engine and
 autoscaler.
-
-``repro.obs.regress`` — the cross-PR ``BENCH_pr<N>.json`` regression
-gate behind ``tools/bench_regress.py`` / ``make bench-regress``.
 """
 from repro.obs.analyze import (analyze, overlap_efficiency,
                                pipeline_accounting, request_latencies,

@@ -32,8 +32,16 @@ Usage::
     with tracing("out.json") as rec:
         trainer.fit(...)                  # instrumented spine records
 
-This module is dependency-free (stdlib only) so every subsystem can
-import it without cycles.
+Device clock
+------------
+``span`` is the hot path's host span for the JAX profiler: it always
+enters a ``jax.profiler.TraceAnnotation``, which lands in a profiler
+trace (``jax.profiler.trace``) on the device trace's clock and costs
+about a microsecond when no profiler runs, and it also records into the
+installed recorder when asked to and the recorder is enabled.
+
+This module is dependency-free (stdlib only; jax is imported on the
+first ``span``) so every subsystem can import it without cycles.
 """
 from __future__ import annotations
 
@@ -231,6 +239,51 @@ def set_recorder(rec) -> Any:
     prev = _recorder
     _recorder = rec if rec is not None else _NULL
     return prev
+
+
+_annotation: Any = None   # jax.profiler.TraceAnnotation, on first use
+
+
+class span:
+    """A host span on the device trace's clock.
+
+    ``with span("train.step", step=t):`` enters
+    ``jax.profiler.TraceAnnotation(name, **args)``, which a running JAX
+    profiler writes into its trace beside the device's operations.  When
+    ``record`` names an event and the installed recorder is enabled, the
+    recorder also gets the span ``record`` with the same ``args`` (and
+    ``pid`` / ``tid`` / ``cat`` / ``clock``, which go to the recorder
+    only), so the virtual-tick trace keeps its own names.  Needs no
+    ``rec.enabled`` guard: off the profiler it costs one annotation."""
+    __slots__ = ("ann", "record", "kw", "args", "rec")
+
+    def __init__(self, name: str, record: Optional[str] = None, *,
+                 pid: str = "main", tid: str = "main", cat: str = "",
+                 clock: Optional[Tuple[str, Any]] = None, **args):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        self.ann = _annotation(name, **args)
+        self.record, self.args = record, args
+        self.kw = (pid, tid, cat, clock)
+        self.rec = None
+
+    def __enter__(self):
+        self.ann.__enter__()
+        rec = _recorder
+        if self.record is not None and rec.enabled:
+            pid, tid, cat, clock = self.kw
+            rec.begin(self.record, pid=pid, tid=tid, cat=cat, clock=clock,
+                      **self.args)
+            self.rec = rec
+        return None
+
+    def __exit__(self, *exc):
+        if self.rec is not None:
+            self.rec.end(pid=self.kw[0], tid=self.kw[1])
+        self.ann.__exit__(*exc)
+        return False
 
 
 @contextlib.contextmanager

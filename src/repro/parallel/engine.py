@@ -52,7 +52,7 @@ from repro.core.pipeline import (bubble_fraction, gpipe_forward, gpipe_ticks,
                                  onefb_bubble_fraction, onefb_forward,
                                  onefb_ticks)
 from repro.core.precision import policy_for
-from repro.obs.trace import get_recorder
+from repro.obs.trace import get_recorder, span
 from repro.core.sync import default_periods
 from repro.launch.mesh import make_hybrid_mesh
 from repro.parallel.mesh_plan import AXES, MeshPlan, MeshSpec, plan_mesh
@@ -656,8 +656,10 @@ class HybridEngine:
                 # the compressed ring AR/RS of the ZeRO bucket update;
                 # parameters always travel exact (docs/comm.md)
                 if cfg.zero == 0:
-                    avg, ef_new, sent = comm.exchange(grads, ef_l, key)
-                    p_out, opt_new = opt_step0(p_local, avg, opt)
+                    with jax.named_scope("exchange"):
+                        avg, ef_new, sent = comm.exchange(grads, ef_l, key)
+                    with jax.named_scope("optimizer"):
+                        p_out, opt_new = opt_step0(p_local, avg, opt)
                     ef_out = (jax.tree.map(expand3, ef_new)
                               if ef_new is not None else ef)
                 else:
@@ -677,20 +679,24 @@ class HybridEngine:
 
                     def grad_reduce(padded, _j):
                         keybox[0], sub = jax.random.split(keybox[0])
-                        if cfg.zero == 1:
-                            red, res, nz = compressed_allreduce(
-                                padded, DATA, "ring", codec, sub)
-                            shard = shard_of_flat(red, DATA)
-                        else:
-                            shard, res, nz = compressed_reduce_scatter(
-                                padded, DATA, codec, sub)
+                        with jax.named_scope("exchange"):
+                            if cfg.zero == 1:
+                                red, res, nz = compressed_allreduce(
+                                    padded, DATA, "ring", codec, sub)
+                                shard = shard_of_flat(red, DATA)
+                            else:
+                                shard, res, nz = compressed_reduce_scatter(
+                                    padded, DATA, codec, sub)
                         resids.append(res)
                         nz_acc.append(nz)
                         return shard
 
-                    new_buckets, opt_new = zero_update(
-                        p_buckets, g_buckets, opt_l,
-                        grad_reduce=grad_reduce)
+                    # the ZeRO update reduces each bucket (``exchange``,
+                    # nested) before its shard's optimizer step
+                    with jax.named_scope("optimizer"):
+                        new_buckets, opt_new = zero_update(
+                            p_buckets, g_buckets, opt_l,
+                            grad_reduce=grad_reduce)
                     sent = sum(nz_acc, sent)
                     p_out, opt_new = zero_unpack(new_buckets, opt_new, opt)
                     if ef_l is not None:
@@ -708,21 +714,27 @@ class HybridEngine:
                         ef_out = ef
             else:
                 if comp.method != "none":
-                    grads, ef_new, _wb = comp.roundtrip(grads, ef_l, key)
+                    with jax.named_scope("exchange"):
+                        grads, ef_new, _wb = comp.roundtrip(grads, ef_l, key)
                     ef_out = (jax.tree.map(expand3, ef_new)
                               if ef_new is not None else ef)
                 else:
                     ef_out = ef
                 if cfg.zero == 0:
-                    avg = reduce0(grads)
-                    p_out, opt_new = opt_step0(p_local, avg, opt)
+                    with jax.named_scope("exchange"):
+                        avg = reduce0(grads)
+                    with jax.named_scope("optimizer"):
+                        p_out, opt_new = opt_step0(p_local, avg, opt)
                 else:
                     g_leaves = jax.tree.leaves(grads)
                     g_buckets = [flatten_bucket(g_leaves, plan.buckets[b])
                                  for b in plan.order]
                     p_buckets, opt_l = zero_buckets(pstate, opt, p_local)
-                    new_buckets, opt_new = zero_update(p_buckets, g_buckets,
-                                                       opt_l)
+                    # the ZeRO update's reduce-scatter and all-gather run
+                    # inside it
+                    with jax.named_scope("optimizer"):
+                        new_buckets, opt_new = zero_update(
+                            p_buckets, g_buckets, opt_l)
                     p_out, opt_new = zero_unpack(new_buckets, opt_new, opt)
             return p_out, opt_new, ef_out, loss[None], expand3(sent)
 
@@ -750,26 +762,33 @@ class HybridEngine:
             self._step_fn, self._act_cell = self._build_step()
             self._measured_tx = self._measured_step_tx_bytes()
         D = cfg.mesh.data
-        per = [batches(t, w) for w in range(D)]
-        if self.staged and cfg.mesh.stage > 1:
-            bsz = int(np.shape(self.model.inputs(per[0]))[0])
-            if bsz % self.plan.micro:
-                raise ValueError(
-                    f"batch size {bsz} not divisible into "
-                    f"{self.plan.micro} micro-batches")
-        batch = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
-        st["rng"], sub = jax.random.split(st["rng"])
         rec = get_recorder()
-        if rec.enabled:
-            with rec.span("compute", pid="train", tid="loop", cat="train",
+        with span("train.step.feed"):
+            per = [batches(t, w) for w in range(D)]
+            if self.staged and cfg.mesh.stage > 1:
+                bsz = int(np.shape(self.model.inputs(per[0]))[0])
+                if bsz % self.plan.micro:
+                    raise ValueError(
+                        f"batch size {bsz} not divisible into "
+                        f"{self.plan.micro} micro-batches")
+            batch = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+        with span("train.step.dispatch"):
+            st["rng"], sub = jax.random.split(st["rng"])
+            if rec.enabled:
+                # the fused mesh step cannot be split at runtime: the
+                # compute span covers its dispatch and the wait for its
+                # results
+                rec.begin("compute", pid="train", tid="loop", cat="train",
                           clock=("train_step", t), mesh=cfg.mesh.spec(),
-                          zero=cfg.zero, fused=True):
-                params, opt, ef, losses, sent = self._step_fn(
-                    st["params"], st["opt"], st["ef"], batch, sub)
-                jax.block_until_ready(losses)
-        else:
+                          zero=cfg.zero, fused=True)
             params, opt, ef, losses, sent = self._step_fn(
                 st["params"], st["opt"], st["ef"], batch, sub)
+        with span("train.step.wait"):
+            loss = float(np.mean(np.asarray(losses)))
+            sent_elems = (int(np.sum(np.asarray(sent)))
+                          if cfg.wire == "measured" else 0)
+            if rec.enabled:
+                rec.end(pid="train", tid="loop")
         st.update(params=params, opt=opt, ef=ef)
         if rec.enabled:
             if D > 1 and cfg.zero == 0:
@@ -790,14 +809,13 @@ class HybridEngine:
             # the data-axis schedule on every device + dgc's traced
             # per-step sparse payload
             st["wire"] += self._measured_tx * cfg.mesh.size \
-                + SPARSE_ELEM_BYTES * int(np.sum(np.asarray(sent)))
+                + SPARSE_ELEM_BYTES * sent_elems
         else:
             st["wire"] += self._modeled_event_bytes() * cfg.mesh.size
         if rec.enabled:
             rec.counter("wire_bytes", {"cumulative": int(st["wire"])},
                         pid="train", cat="comm", clock=("train_step", t))
-        ev = dict(step=t, loss=float(np.mean(np.asarray(losses))),
-                  max_staleness=0)
+        ev = dict(step=t, loss=loss, max_staleness=0)
         return st, [ev]
 
     def step(self, st, batches: Callable[[int, int], Any], t: int):
@@ -1185,15 +1203,11 @@ class HybridEngine:
     def run(self, params, batches: Callable[[int, int], Any], steps: int):
         st = self.init(params)
         hist: List[dict] = []
-        rec = get_recorder()
         for t in range(steps):
             # same step spans train_loop emits for the flat engines, so
             # hybrid traces feed obs.analyze.step_attribution too
-            if rec.enabled:
-                with rec.span("step", pid="train", tid="loop", cat="train",
-                              clock=("train_step", t), step=t):
-                    st, ev = self.step(st, batches, t)
-            else:
+            with span("train.step", "step", pid="train", tid="loop",
+                      cat="train", clock=("train_step", t), step=t):
                 st, ev = self.step(st, batches, t)
             hist.extend(ev)
         return self.finalize(st), hist, st["wire"]

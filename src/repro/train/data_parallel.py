@@ -73,7 +73,7 @@ from repro.core.parameter_server import make_ps_step, sgd_update_fn
 from repro.core.sync import (ElasticWorkerSet, default_periods,
                              firing_schedule, warn_deprecated)
 from repro.elastic.backup import participation_weights
-from repro.obs.trace import get_recorder
+from repro.obs.trace import get_recorder, span
 
 AXIS = "workers"
 
@@ -401,29 +401,34 @@ class DeviceEngine(ElasticWorkerSet):
             wt = weight[0]
             loss, grads = self.grad_fn(params, batch)
             sent = jnp.zeros((), jnp.int32)
-            if in_schedule:
-                # compressed payloads ride *inside* the schedule: the
-                # CommPlan encodes each bucket's compensated gradient,
-                # permutes the planes, and returns the per-worker hop
-                # residuals as the new EF contribution (docs/comm.md)
-                g_in = jax.tree.map(lambda x: x * wt, grads)
-                if cfg.arch == "ps":
-                    new_params, ef_new, sent = plan.ps_exchange(
-                        params, g_in, ef_in, rng, cfg.lr)
+            # device-trace scopes: ``exchange`` (weights, codec,
+            # collectives; the parameter server's fused update too) and
+            # ``optimizer`` (the allreduce arch's SGD update)
+            with jax.named_scope("exchange"):
+                if in_schedule:
+                    # compressed payloads ride *inside* the schedule: the
+                    # CommPlan encodes each bucket's compensated gradient,
+                    # permutes the planes, and returns the per-worker hop
+                    # residuals as the new EF contribution (docs/comm.md)
+                    g_in = jax.tree.map(lambda x: x * wt, grads)
+                    if cfg.arch == "ps":
+                        new_params, ef_new, sent = plan.ps_exchange(
+                            params, g_in, ef_in, rng, cfg.lr)
+                    else:
+                        avg, ef_new, sent = plan.exchange(g_in, ef_in, rng)
                 else:
-                    avg, ef_new, sent = plan.exchange(g_in, ef_in, rng)
-                    new_params = jax.tree.map(
-                        lambda p, g: p - cfg.lr * g, params, avg)
-            else:
-                if comp.method != "none":
-                    grads, ef_new, _wb = comp.roundtrip(grads, ef_in, rng)
-                else:
-                    ef_new = ef_in
-                grads = jax.tree.map(lambda x: x * wt, grads)
-                if cfg.arch == "ps":
-                    new_params = bucketed_ps(params, grads)
-                else:
-                    avg = plan.reduce_grads(grads)
+                    if comp.method != "none":
+                        grads, ef_new, _wb = comp.roundtrip(grads, ef_in,
+                                                            rng)
+                    else:
+                        ef_new = ef_in
+                    grads = jax.tree.map(lambda x: x * wt, grads)
+                    if cfg.arch == "ps":
+                        new_params = bucketed_ps(params, grads)
+                    else:
+                        avg = plan.reduce_grads(grads)
+            if cfg.arch != "ps":
+                with jax.named_scope("optimizer"):
                     new_params = jax.tree.map(lambda p, g: p - cfg.lr * g,
                                               params, avg)
             if ef_new is not None:
@@ -458,42 +463,48 @@ class DeviceEngine(ElasticWorkerSet):
         # backup_drop rule the simulator applies)
         drop = self.backup_drop(cfg.backup)
         weights = participation_weights(K, drop)
-        if self.detector is not None:
-            # per-worker batch fetch is the only per-worker host work in
-            # the fused device step — measure it (a straggling input
-            # pipeline is the detectable straggler here)
-            per_worker = []
-            for w in range(K):
-                t0 = time.perf_counter()
-                per_worker.append(batches(t, w))
-                self.detector.observe(w, time.perf_counter() - t0)
-        else:
-            per_worker = [batches(t, w) for w in range(K)]
-        batch = jax.tree.map(lambda *xs: jnp.stack(xs), *per_worker)
-        st["rng"], *subs = jax.random.split(st["rng"], K + 1)
         rec = get_recorder()
-        if rec.enabled:
-            # the fused shard_map step cannot be split at runtime, so the
-            # compute span covers the whole dispatch (blocked for an
-            # honest wall_s) and the exchange structure below is the
-            # plan's deterministic model of what ran inside it
-            with rec.span("compute", pid="train", tid="loop", cat="train",
-                          clock=("train_step", t), workers=K, fused=True):
-                params, ef, losses, sent = self._step_fn(
-                    st["params"], st["ef"], batch, jnp.stack(subs),
-                    jnp.asarray(weights))
-                jax.block_until_ready(losses)
-        else:
+        with span("train.step.feed"):
+            if self.detector is not None:
+                # per-worker batch fetch is the only per-worker host work
+                # in the fused device step — measure it (a straggling
+                # input pipeline is the detectable straggler here)
+                per_worker = []
+                for w in range(K):
+                    t0 = time.perf_counter()
+                    per_worker.append(batches(t, w))
+                    self.detector.observe(w, time.perf_counter() - t0)
+            else:
+                per_worker = [batches(t, w) for w in range(K)]
+            batch = jax.tree.map(lambda *xs: jnp.stack(xs), *per_worker)
+        with span("train.step.dispatch"):
+            st["rng"], *subs = jax.random.split(st["rng"], K + 1)
+            rngs, wts = jnp.stack(subs), jnp.asarray(weights)
+            if rec.enabled:
+                # the fused shard_map step cannot be split at runtime, so
+                # the compute span covers its dispatch and the wait for
+                # its results, and the exchange structure below is the
+                # plan's deterministic model of what ran inside it
+                rec.begin("compute", pid="train", tid="loop", cat="train",
+                          clock=("train_step", t), workers=K, fused=True)
             params, ef, losses, sent = self._step_fn(
-                st["params"], st["ef"], batch, jnp.stack(subs),
-                jnp.asarray(weights))
+                st["params"], st["ef"], batch, rngs, wts)
+        with span("train.step.wait"):
+            # participant-mean loss, float64 like the simulator's
+            # accounting; dgc's traced sparse payload, all workers
+            part_losses = [float(losses[w]) for w in range(K)
+                           if w not in drop]
+            sent_elems = (int(np.sum(np.asarray(sent)))
+                          if cfg.wire == "measured" else 0)
+            if rec.enabled:
+                rec.end(pid="train", tid="loop")
         st.update(params=params, ef=ef)
         if cfg.wire == "measured":
             # recomputed per bucket from the plan, every step: the
             # shape-static plane bytes of the whole schedule plus dgc's
-            # per-step sparse payload (traced sent_elems, all workers)
+            # per-step sparse payload
             wire_inc = plan.measured_step_tx_bytes(cfg.arch) * K \
-                + SPARSE_ELEM_BYTES * int(np.sum(np.asarray(sent)))
+                + SPARSE_ELEM_BYTES * sent_elems
         else:
             wire_inc = self._event_wire_bytes(st["params"]) \
                 * (K - len(drop))
@@ -503,8 +514,6 @@ class DeviceEngine(ElasticWorkerSet):
             rec.counter("wire_bytes", {"cumulative": int(st["wire"])},
                         pid="train", cat="comm", clock=("train_step", t))
         self._dropped += len(drop)
-        # participant-mean loss, float64 like the simulator's accounting
-        part_losses = [float(losses[w]) for w in range(K) if w not in drop]
         ev = dict(step=t, loss=float(np.mean(part_losses)), max_staleness=0)
         if drop:
             ev["dropped"] = sorted(drop)

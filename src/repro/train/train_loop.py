@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.compression import Compressor, EF_METHODS
 from repro.core.precision import PrecisionPolicy, DEFAULT
-from repro.obs.trace import get_recorder
+from repro.obs.trace import span
 from repro.optim.schedule import constant
 
 
@@ -84,21 +84,18 @@ def train_loop(train_step, state, batch_fn: Callable[[int], Any],
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     hist = []
     t0 = time.time()
-    tracer = get_recorder()     # no-op by default: tracing off is free
     for t in range(steps):
-        rng, sub = jax.random.split(rng)
-        if tracer.enabled:
-            # one span per global step on the deterministic step clock;
-            # engines emit their compute/exchange sub-spans on the same
-            # track (docs/observability.md)
-            with tracer.span("step", pid="train", tid="loop", cat="train",
-                             clock=("train_step", t), step=t):
-                state, mets = step_fn(state, batch_fn(t), sub)
-        else:
+        # one ``train.step`` per global step on the device trace's clock,
+        # and the recorder's ``step`` span on the deterministic step
+        # clock; engines emit their sub-spans inside it
+        # (docs/observability.md)
+        with span("train.step", "step", pid="train", tid="loop",
+                  cat="train", clock=("train_step", t), step=t):
+            rng, sub = jax.random.split(rng)
             state, mets = step_fn(state, batch_fn(t), sub)
-        if t % log_every == 0 or t == steps - 1:
-            rec = {k: float(v) for k, v in mets.items()}
-            rec["step"] = t
-            rec["wall_s"] = time.time() - t0
-            hist.append(rec)
+            if t % log_every == 0 or t == steps - 1:
+                rec = {k: float(v) for k, v in mets.items()}
+                rec["step"] = t
+                rec["wall_s"] = time.time() - t0
+                hist.append(rec)
     return state, hist

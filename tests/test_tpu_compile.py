@@ -166,3 +166,58 @@ def test_topk_compress(one_chip):
     t = _shape(one_chip, ())
     _assert_kernel(functools.partial(topk_compress, interpret=False),
                    g, g, t)
+
+
+def test_step_scopes_change_only_metadata(topo, one_chip, monkeypatch):
+    """The training step compiled for the chip is the same program with
+    and without the model's and the engine's ``jax.named_scope``s: they
+    name ops in the device trace and change nothing else."""
+    import contextlib
+    import dataclasses
+    import re
+
+    import repro.models.attention as attention
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.train import Strategy
+
+    monkeypatch.setattr(attention, "kernel_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        get_config("stablelm-1.6b"), d_model=256, num_heads=4,
+        num_kv_heads=4, head_dim=HD, d_ff=512, vocab_size=512, num_layers=2,
+        attn_backend="kernel")
+    model = build_model(cfg)
+
+    def grad_fn(p, b):
+        (loss, _), g = jax.value_and_grad(
+            lambda q: model.loss_fn(q, b), has_aux=True)(p)
+        return loss, g
+    engine = Strategy.parse("bsp/allreduce/none@1", lr=0.1).build(
+        grad_fn, devices=[topo.devices[0]]).inner
+    rep, per = (NamedSharding(engine.mesh, P()),
+                NamedSharding(engine.mesh, P("workers")))
+    params = jax.tree.map(lambda a: _shape(rep, a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {k: _shape(per, (1, 2, 256), jnp.int32)
+             for k in ("tokens", "labels")}
+    keys = _shape(per, (1, 2), jnp.uint32)
+    weight = _shape(per, (1,))
+
+    def compiled_text():
+        jax.clear_caches()
+        step = engine._build_step(params)
+        text = step.lower(params, None, batch, keys, weight).compile() \
+            .as_text()
+        # the ops without their metadata and the source-location tables
+        # (file, function, line) that it points into
+        ops = [p for p in text.split("\n\n") if p.split("\n", 1)[0] not in
+               ("FileNames", "FunctionNames", "FileLocations", "StackFrames")]
+        return text, re.sub(r",? metadata=\{[^}]*\}", "", "\n\n".join(ops))
+
+    scoped, scoped_bare = compiled_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, plain_bare = compiled_text()
+    assert "tpu_custom_call" in scoped
+    assert "/attention/" in scoped and "/attention/" not in plain
+    assert scoped_bare == plain_bare
