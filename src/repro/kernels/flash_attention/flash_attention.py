@@ -1,18 +1,44 @@
-"""Block-tiled online-softmax (flash) attention Pallas TPU kernel.
+"""Block-tiled online-softmax (flash) attention Pallas TPU kernels.
 
-Grid (B, H, nq, nk): the innermost nk axis streams K/V blocks through VMEM
-while float32 VMEM scratch accumulators (running max m, normalizer l, output
-acc) persist across nk steps — the canonical TPU flash schedule.  GQA is
-free: the K/V BlockSpec index_map folds the query head onto its KV head, so
-no repeated K/V ever materializes in VMEM.  Block shapes default to the
-MXU-aligned (128, 128); head_dim is the minor (lane) dimension.
+Training / prefill forward (``flash_attention``)
+    q, k and v are read in the model's own layout, viewed for free as
+    ``[B, S, H*hd]`` and ``[B, S, KV*hd]``: one block holds ``heads`` query
+    heads side by side on the lane axis, and the KV heads they attend to,
+    so GQA folds into the K/V index map and no repeated K/V ever reaches
+    VMEM.  Grid ``(B, H / heads, nq, nk)``: the innermost nk axis streams
+    K/V blocks through VMEM while float32 scratch (the output accumulator,
+    and the running max m and normaliser l in a lane-dense ``(bq, 128)``
+    per head) persists across it.
 
-Causal / sliding-window masking is applied per-block from global positions.
-``interpret=True`` executes the kernel body on the CPU; on TPU hardware
-pass interpret=False.
+    Blocks come from the input's shape (``plan``): ``heads`` gives a block
+    at least 256 lanes wide where the heads allow it; the sequence block is
+    512 for long sequences (fewer rows where the window is short, or where
+    VMEM would not hold the step), and the sequence itself, rounded up to
+    the sublane tile, when it is shorter.  Explicit ``block_q`` /
+    ``block_k`` override the sequence blocks.
+
+    Causal and window block skipping: a (q-block, k-block) pair wholly
+    above the diagonal, or wholly before the window, runs no compute, and
+    the K/V index map is clamped to the blocks the q-block needs, so a
+    skipped step issues no DMA.  The mask is built only on blocks that
+    straddle the diagonal, the window's edge or the padded key tail.
+    ``plan(...).pairs`` counts the pairs that compute.
+
+    Operands reach the MXU in their own dtype with float32 accumulation:
+    QK^T with q pre-scaled by 1/sqrt(hd) (exact where hd is a power of 4),
+    PV with the probabilities cast to V's dtype.  m, l, the accumulator
+    and the softmax stay float32; float32 inputs give float32 dots.
+
+One-token decode (``flash_decode``)
+    Streams ``[B, L, KV, hd]`` cache blocks through VMEM with the same
+    online-softmax scratch; see its docstring.
+
+``interpret=True`` executes a kernel body on the CPU; on TPU hardware pass
+interpret=False.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -21,13 +47,138 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+MAX_BLOCK = 512                 # longest sequence block the plan picks
+VMEM_BUDGET = 12 * 2 ** 20      # bytes one forward grid step may hold
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashPlan:
+    """The forward kernel's schedule for one input shape."""
+    block_q: int
+    block_k: int
+    heads: int       # query heads per grid step
+    kv_heads: int    # KV heads per grid step
+    nq: int
+    nk: int
+    pairs: int       # (q-block, k-block) pairs that compute, of nq * nk
+
+
+def _kv_range(iq, *, bq: int, bk: int, nk: int, causal: bool, window: int,
+              lo=max, hi=min):
+    """First and last k-block that q-block ``iq`` attends to.  ``lo`` /
+    ``hi`` are ``max`` / ``min`` for Python ints and ``jnp.maximum`` /
+    ``jnp.minimum`` for a traced block index."""
+    if not causal:
+        return 0, nk - 1
+    first = lo(iq * bq - window + 1, 0) // bk if window else 0
+    return first, hi((iq * bq + bq - 1) // bk, nk - 1)
+
+
+def _head_block(H: int, KV: int, hd: int) -> tuple:
+    """Fewest query heads per block (with the KV heads they read) whose
+    lane widths tile by 128, or span the array, reaching 256 lanes."""
+    group = H // KV
+    for hb in (d for d in range(1, H) if H % d == 0):
+        if hb % group and group % hb:
+            continue
+        kvb = max(1, hb // group)
+        if hb * hd >= 2 * LANES and all(
+                n == total or n * hd % LANES == 0
+                for n, total in ((hb, H), (kvb, KV))):
+            return hb, kvb
+    return H, KV                  # every head: the blocks span the arrays
+
+
+def _vmem_bytes(bq: int, bk: int, hb: int, kvb: int, hd: int,
+                itemsize: int) -> int:
+    io = 2 * (2 * bq * hb + 2 * bk * kvb) * hd * itemsize  # q, o, k, v x2
+    scratch = hb * bq * (max(hd, LANES) + 2 * LANES) * 4   # acc, m, l
+    return io + scratch + 4 * bq * bk * 4                  # one head's s, p
+
+
+def plan(S: int, H: int, KV: int, hd: int, dtype, *, causal: bool = True,
+         window: int = 0, block_q: int | None = None,
+         block_k: int | None = None) -> FlashPlan:
+    """Blocks for a ``[B, S, H, hd]`` forward from the shape alone."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // itemsize                  # sublane tile: 8 f32, 16 bf16
+    hb, kvb = _head_block(H, KV, hd)
+    target = MAX_BLOCK
+    if causal and window:
+        target = min(target, max(LANES, _round_up(window, LANES)))
+    while True:
+        if S <= target:
+            b = _round_up(S, sub)
+        else:   # least padding, then the largest block
+            b = min((c for c in (512, 256, 128) if c <= target),
+                    key=lambda c: (_round_up(S, c), -c))
+        if target == LANES or \
+                _vmem_bytes(b, b, hb, kvb, hd, itemsize) <= VMEM_BUDGET:
+            break
+        target = max(LANES, target // 2)
+    bq = min(block_q, _round_up(S, sub)) if block_q else b
+    bk = min(block_k, _round_up(S, sub)) if block_k else b
+    nq, nk = -(-S // bq), -(-S // bk)
+    kw = dict(bq=bq, bk=bk, nk=nk, causal=causal, window=window)
+    pairs = sum(last - first + 1 for first, last in
+                (_kv_range(iq, **kw) for iq in range(nq)))
+    return FlashPlan(bq, bk, hb, kvb, nq, nk, pairs)
+
+
+def _lanes(x, n: int):
+    """A ``[rows, 128]`` array whose lanes are equal, as ``[rows, n]``."""
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    if n < LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, masked: bool,
+            q0, k0, scale: float, causal: bool, window: int, bq: int,
+            bk: int, seq_len: int, heads: int, group: int, hd: int):
+    """One (q-block, k-block) pair for every head of the block."""
+    if masked:
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        mask = kpos < seq_len                            # key padding
+        if causal:
+            mask &= kpos <= qpos
+            if window:
+                mask &= kpos > qpos - window
+    for h in range(heads):
+        c = h // group                                   # its KV head
+        q = q_ref[0, :, h * hd:(h + 1) * hd]
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        k = k_ref[0, :, c * hd:(c + 1) * hd]
+        v = v_ref[0, :, c * hd:(c + 1) * hd]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[h]                                # [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - _lanes(m_new, bk))               # [bq, bk]
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[h] = m_new
+        acc_ref[h] = acc_ref[h] * _lanes(alpha, hd) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale: float, causal: bool, window: int, bq: int, bk: int,
-            nk: int, seq_len: int):
+            nk: int, seq_len: int, heads: int, group: int, hd: int):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
+    first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
+                            window=window, lo=jnp.maximum, hi=jnp.minimum)
 
     @pl.when(ik == 0)
     def _init():
@@ -35,81 +186,92 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # [bq, hd]
-    k = k_ref[0, 0].astype(jnp.float32)                  # [bk, hd]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [bq, bk]
-
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = kpos < seq_len                                # key padding
+    q0, k0 = iq * bq, ik * bk
+    run = (ik >= first) & (ik <= last)
+    clean = k0 + bk <= seq_len                # every pair of it is visible
     if causal:
-        mask &= kpos <= qpos
+        clean &= k0 + bk - 1 <= q0
         if window:
-            mask &= kpos > qpos - window
-    s = jnp.where(mask, s, NEG_INF)
+            clean &= k0 > q0 + bq - 1 - window
+    update = functools.partial(
+        _update, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, q0=q0, k0=k0,
+        scale=scale, causal=causal, window=window, bq=bq, bk=bk,
+        seq_len=seq_len, heads=heads, group=group, hd=hd)
 
-    m_prev = m_ref[...]                                  # [bq, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                               # [bq, bk]
-    v = v_ref[0, 0].astype(jnp.float32)                  # [bk, hd]
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[...] = m_new
+    @pl.when(run & clean)
+    def _whole():
+        update(masked=False)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(run & jnp.logical_not(clean))
+    def _edge():
+        update(masked=True)
+
+    @pl.when(ik == last)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for h in range(heads):
+            l = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, :, h * hd:(h + 1) * hd] = (
+                acc_ref[h] / _lanes(l, hd)).astype(o_ref.dtype)
+
+
+def _pad_seq(x, n: int):
+    return x if x.shape[1] == n else \
+        jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: int | None = None, block_k: int | None = None,
                     interpret: bool = True):
-    """q [B, S, H, hd]; k, v [B, S, KV, hd] (KV divides H) -> [B, S, H, hd]."""
+    """q [B, S, H, hd]; k, v [B, S, KV, hd] (KV divides H) -> [B, S, H, hd].
+
+    Blocks come from ``plan``; ``block_q`` / ``block_k`` override its
+    sequence blocks."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     group = H // KV
-    scale = 1.0 / (hd ** 0.5)
+    pn = plan(S, H, KV, hd, q.dtype, causal=causal, window=window,
+              block_q=block_q, block_k=block_k)
+    bq, bk, hb, kvb, nq, nk = (pn.block_q, pn.block_k, pn.heads,
+                               pn.kv_heads, pn.nq, pn.nk)
+    # free views [B, S, heads*hd], padded to whole blocks
+    qf = _pad_seq(q.reshape(B, S, H * hd), nq * bq)
+    kf = _pad_seq(k.reshape(B, S, KV * hd), nk * bk)
+    vf = _pad_seq(v.reshape(B, S, KV * hd), nk * bk)
 
-    # [B, H, S, hd] layout, pad S to block multiples
-    qt = jnp.moveaxis(q, 2, 1)
-    kt = jnp.moveaxis(k, 2, 1)
-    vt = jnp.moveaxis(v, 2, 1)
-    bq = min(block_q, max(8, S))
-    bk = min(block_k, max(8, S))
-    sq_pad = (S + bq - 1) // bq * bq
-    sk_pad = (S + bk - 1) // bk * bk
-    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, sq_pad - S), (0, 0)))
-    kt = jnp.pad(kt, ((0, 0), (0, 0), (0, sk_pad - S), (0, 0)))
-    vt = jnp.pad(vt, ((0, 0), (0, 0), (0, sk_pad - S), (0, 0)))
-    nq, nk = sq_pad // bq, sk_pad // bk
+    def q_map(b, h, iq, ik):
+        return b, iq, h
+
+    def kv_map(b, h, iq, ik):
+        # a skipped step maps to a block the q-block needs: no new DMA
+        first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
+                                window=window, lo=jnp.maximum,
+                                hi=jnp.minimum)
+        return b, jnp.minimum(jnp.maximum(ik, first), last), \
+            h * hb // (group * kvb)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal, window=window,
-                          bq=bq, bk=bk, nk=nk, seq_len=S),
-        grid=(B, H, nq, nk),
+        functools.partial(_kernel, scale=1.0 / hd ** 0.5, causal=causal,
+                          window=window, bq=bq, bk=bk, nk=nk, seq_len=S,
+                          heads=hb, group=group, hd=hd),
+        grid=(B, H // hb, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, h, iq, ik, _g=group: (b, h // _g, ik, 0)),
-            pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, h, iq, ik, _g=group: (b, h // _g, ik, 0)),
+            pl.BlockSpec((1, bq, hb * hd), q_map),
+            pl.BlockSpec((1, bk, kvb * hd), kv_map),
+            pl.BlockSpec((1, bk, kvb * hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd),
-                               lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, sq_pad, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hb * hd), q_map),
+        out_shape=jax.ShapeDtypeStruct((B, nq * bq, H * hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, hd), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((hb, bq, hd), jnp.float32),
+            pltpu.VMEM((hb, bq, LANES), jnp.float32),
+            pltpu.VMEM((hb, bq, LANES), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-    )(qt, kt, vt)
-    return jnp.moveaxis(out[:, :, :S, :], 1, 2)
+    )(qf, kf, vf)
+    return out[:, :S].reshape(B, S, H, hd)
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref,

@@ -20,7 +20,8 @@ from repro.kernels.flash_attention.ref import attention_ref, decode_ref
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
-              block_q: int = 128, block_k: int = 128, interpret: bool = True):
+              block_q: int | None = None, block_k: int | None = None,
+              interpret: bool = True):
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)

@@ -34,33 +34,96 @@ def test_resolve_backend_contract(monkeypatch):
 
 
 # ------------------------------------------------------------ flash attention
-@pytest.mark.parametrize("B,S,H,KV,hd", [
-    (1, 64, 4, 4, 32), (2, 64, 4, 2, 32), (1, 128, 8, 1, 64),
-    (2, 96, 4, 2, 64), (1, 256, 2, 2, 128),
+def _qkv(B, S, H, KV, hd, dtype=jnp.float32):
+    ks = jax.random.split(KEY, 3)
+    return (jax.random.normal(ks[0], (B, S, H, hd), dtype),
+            jax.random.normal(ks[1], (B, S, KV, hd), dtype),
+            jax.random.normal(ks[2], (B, S, KV, hd), dtype))
+
+
+def _max_err(out, ref):
+    return float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                 - ref.astype(jnp.float32))))
+
+
+# block None: the blocks ``plan`` derives from the shape
+@pytest.mark.parametrize("B,S,H,KV,hd,block", [
+    pytest.param(1, 64, 4, 4, 32, 64, id="1-64-4-4-32"),
+    pytest.param(2, 64, 4, 2, 32, 64, id="2-64-4-2-32"),
+    pytest.param(1, 128, 8, 1, 64, 64, id="1-128-8-1-64"),
+    pytest.param(2, 96, 4, 2, 64, 64, id="2-96-4-2-64"),
+    pytest.param(1, 256, 2, 2, 128, 64, id="1-256-2-2-128"),
+    # S not a multiple of the block: one planned block of S rounded up,
+    # and a padded key tail behind explicit blocks
+    pytest.param(1, 200, 4, 4, 32, None, id="1-200-4-4-32-planned"),
+    pytest.param(1, 200, 4, 2, 32, 64, id="1-200-4-2-32-b64"),
+    # whole blocks above the diagonal skipped (10 of 16 pairs compute)
+    pytest.param(1, 512, 2, 2, 64, 128, id="1-512-2-2-64-b128"),
+    # planned 512-row blocks, GQA folded (KV < H), one pair skipped
+    pytest.param(1, 1024, 4, 2, 64, None, id="1-1024-4-2-64-planned"),
+    pytest.param(2, 256, 8, 2, 64, None, id="2-256-8-2-64-planned"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, S, H, KV, hd, dtype):
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
-    k = jax.random.normal(ks[1], (B, S, KV, hd), dtype)
-    v = jax.random.normal(ks[2], (B, S, KV, hd), dtype)
-    out = FA.attention(q, k, v, causal=True, block_q=64, block_k=64)
+def test_flash_attention_sweep(B, S, H, KV, hd, block, dtype):
+    q, k, v = _qkv(B, S, H, KV, hd, dtype)
+    out = FA.attention(q, k, v, causal=True, block_q=block, block_k=block)
     ref = FA.attention_ref(q, k, v, causal=True)
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
-    assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
-                                 - ref.astype(jnp.float32)))) < tol
+    assert _max_err(out, ref) < tol
 
 
-@pytest.mark.parametrize("window", [16, 64])
-def test_flash_attention_window(window):
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 128, 4, 32))
-    k = jax.random.normal(ks[1], (1, 128, 2, 32))
-    v = jax.random.normal(ks[2], (1, 128, 2, 32))
+@pytest.mark.parametrize("window,S,block,dtype", [
+    pytest.param(16, 128, 32, jnp.float32, id="16"),
+    pytest.param(64, 128, 32, jnp.float32, id="64"),
+    # a window shorter than the planned block: only the diagonal blocks
+    pytest.param(16, 512, None, jnp.float32, id="16-S512-planned"),
+    # windows spanning several blocks: blocks before them skipped
+    pytest.param(200, 512, 128, jnp.bfloat16, id="200-S512-b128-bf16"),
+    pytest.param(300, 1024, None, jnp.bfloat16, id="300-S1024-planned-bf16"),
+])
+def test_flash_attention_window(window, S, block, dtype):
+    q, k, v = _qkv(1, S, 4, 2, 32, dtype)
     out = FA.attention(q, k, v, causal=True, window=window,
-                       block_q=32, block_k=32)
+                       block_q=block, block_k=block)
     ref = FA.attention_ref(q, k, v, causal=True, window=window)
-    assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    assert _max_err(out, ref) < tol
+
+
+@pytest.mark.parametrize("S,H,KV,hd,dtype,block,heads,pairs,grid", [
+    (2048, 32, 32, 64, jnp.bfloat16, 512, 4, 10, 16),    # the train cell
+    (48, 8, 2, 32, jnp.float32, 48, 8, 1, 1),            # a short prefill
+    (1280, 16, 16, 128, jnp.bfloat16, 256, 2, 15, 25),   # 512 would pad
+])
+def test_flash_attention_plan(S, H, KV, hd, dtype, block, heads, pairs,
+                              grid):
+    """The plan's blocks for a shape, and its count of computed block
+    pairs against a brute-force count of pairs holding a visible key."""
+    from repro.kernels.flash_attention.flash_attention import plan
+    pn = plan(S, H, KV, hd, dtype)
+    assert (pn.block_q, pn.block_k, pn.heads) == (block, block, heads)
+    assert (pn.pairs, pn.nq * pn.nk) == (pairs, grid)
+    assert pn.heads * hd % 128 == 0 or pn.heads == H
+
+    def brute(S, bq, bk, causal, window):
+        qi = np.arange(S)[:, None]
+        kj = np.arange(S)[None, :]
+        vis = np.ones((S, S), bool)
+        if causal:
+            vis = (kj <= qi) & ((kj > qi - window) if window else True)
+        return sum(vis[i:i + bq, j:j + bk].any()
+                   for i in range(0, S, bq) for j in range(0, S, bk))
+
+    for S_, b, causal, window in [(200, 64, True, 0), (512, 128, True, 0),
+                                  (512, 128, True, 200), (300, 32, True, 16),
+                                  (256, 64, False, 0), (1000, 96, True, 333),
+                                  (48, None, True, 0), (1280, None, True, 0)]:
+        for bq, bk in ((b, b), (b, b and 2 * b)):
+            pn = plan(S_, H, KV, hd, dtype, causal=causal, window=window,
+                      block_q=bq, block_k=bk)
+            assert pn.pairs == brute(S_, pn.block_q, pn.block_k, causal,
+                                     window)
+            assert pn.nq * pn.block_q >= S_ and pn.nk * pn.block_k >= S_
 
 
 def test_flash_attention_noncausal():
@@ -71,6 +134,11 @@ def test_flash_attention_noncausal():
     out = FA.attention(q, k, v, causal=False, block_q=32, block_k=32)
     ref = FA.attention_ref(q, k, v, causal=False)
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
+    # without the causal mask only the key-padding mask hides the padded
+    # tail: 200 keys in 64-row blocks
+    q, k, v = _qkv(1, 200, 4, 2, 32)
+    out = FA.attention(q, k, v, causal=False, block_q=64, block_k=64)
+    assert _max_err(out, FA.attention_ref(q, k, v, causal=False)) < 1e-4
 
 
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 16),

@@ -24,14 +24,17 @@ from repro.comm.transport import ring_allreduce
 
 from repro.kernels.flash_attention import (attention_grad, flash_attention,
                                            flash_decode)
+from repro.kernels.flash_attention.flash_attention import plan
 from repro.kernels.onebit.fused import onebit_encode_ef
 from repro.kernels.onebit.onebit import onebit_compress
 from repro.kernels.qsgd.qsgd import qsgd_compress
 from repro.kernels.terngrad.terngrad import terngrad_ternarize
 from repro.kernels.topk.topk import topk_compress
 
-# StableLM-2 1.6B attention; codec rows as comm/codecs.py lays them out
-B, S, H, HD = 1, 2048, 32, 64
+# StableLM-2 1.6B attention at the train cell's 3 rows of 2048 tokens;
+# codec rows as comm/codecs.py lays them out
+B, S, H, HD = 3, 2048, 32, 64
+WINDOW = 512
 ROWS, LANE = 8192, 256
 D_MODEL, VOCAB = 2048, 100352
 
@@ -69,20 +72,26 @@ def _assert_kernel(fn, *args):
 
 
 def test_flash_attention(one_chip):
+    """The planned blocks (512 rows, four heads a step) fit VMEM, causal
+    and with a window that skips blocks before it."""
     q = _shape(one_chip, (B, S, H, HD), jnp.bfloat16)
-    _assert_kernel(functools.partial(flash_attention, causal=True,
-                                     interpret=False), q, q, q)
+    assert plan(S, H, H, HD, jnp.bfloat16).block_q == 512
+    for window in (0, WINDOW):
+        _assert_kernel(functools.partial(flash_attention, causal=True,
+                                         window=window, interpret=False),
+                       q, q, q)
 
 
 def test_attention_grad_fwd_bwd(one_chip):
     q = _shape(one_chip, (B, S, H, HD), jnp.bfloat16)
-
-    def loss(q, k, v):
-        out = attention_grad(q, k, v, causal=True, interpret=False)
-        return jnp.sum(out.astype(jnp.float32))
-    # value_and_grad: the forward value keeps the kernel live (the VJP
-    # replays the reference math)
-    _assert_kernel(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+    for window in (0, WINDOW):
+        def loss(q, k, v, window=window):
+            out = attention_grad(q, k, v, causal=True, window=window,
+                                 interpret=False)
+            return jnp.sum(out.astype(jnp.float32))
+        # value_and_grad: the forward value keeps the kernel live (the
+        # VJP replays the reference math)
+        _assert_kernel(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 
 @pytest.mark.parametrize("window", [0, 512], ids=["full", "window"])
