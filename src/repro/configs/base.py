@@ -13,6 +13,21 @@ from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (``rope_scaling`` of its config.json): frequencies interpolated by
+    ``factor`` outside the correction range of ``beta_fast`` / ``beta_slow``
+    rotations at ``original_max_position`` positions, and the softmax scale
+    multiplied by ``mscale(factor, mscale_all_dim)**2``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | vlm | audio | hybrid | ssm
@@ -27,6 +42,7 @@ class ModelConfig:
     attn_type: str = "gqa"           # gqa | mla | none
     window: int = 0                  # >0 => sliding-window (local) attention
     rope_theta: float = 10_000.0
+    yarn: Optional[Yarn] = None      # YaRN rotary scaling (deepseek MLA)
     mrope_sections: Tuple[int, ...] = ()   # qwen2-vl M-RoPE (t, h, w) half-dims
     # ---- MLP / MoE ----
     act: str = "swiglu"              # swiglu | gelu
@@ -38,6 +54,11 @@ class ModelConfig:
     first_k_dense: int = 0           # leading dense layers before the MoE stack
     capacity_factor: float = 1.0
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    seq_aux: bool = False            # DeepSeek's per-sequence balance loss
+    # (first, count): the contiguous block of routed experts this layer
+    # holds, computed dropless; () holds all of them, with capacity dispatch
+    experts_held: Tuple[int, ...] = ()
     # ---- MLA (deepseek) ----
     kv_lora_rank: int = 0
     q_lora_rank: int = 0

@@ -2,10 +2,11 @@
 
 Training / prefill forward (``flash_attention``)
     q, k and v are read in the model's own layout, viewed for free as
-    ``[B, S, H*hd]`` and ``[B, S, KV*hd]``: one block holds ``heads`` query
-    heads side by side on the lane axis, and the KV heads they attend to,
-    so GQA folds into the K/V index map and no repeated K/V ever reaches
-    VMEM.  Grid ``(B, H / heads, nq, nk)``: the innermost nk axis streams
+    ``[B, S, H*hd]`` and ``[B, S, KV*hd]`` (v and the output with a head
+    dim ``hdv`` of their own, as MLA's 128 against q/k's 192): one block
+    holds ``heads`` query heads side by side on the lane axis, and the KV
+    heads they attend to, so GQA folds into the K/V index map and no
+    repeated K/V ever reaches VMEM.  Grid ``(B, H / heads, nq, nk)``: the innermost nk axis streams
     K/V blocks through VMEM while float32 scratch (the output accumulator,
     and the running max m and normaliser l in a lane-dense ``(bq, 128)``
     per head) persists across it.
@@ -25,7 +26,8 @@ Training / prefill forward (``flash_attention``)
     ``plan(...).pairs`` counts the pairs that compute.
 
     Operands reach the MXU in their own dtype with float32 accumulation:
-    QK^T with q pre-scaled by 1/sqrt(hd) (exact where hd is a power of 4),
+    QK^T with q pre-scaled by the softmax scale (1/sqrt(hd) unless one is
+    given; exact where hd is a power of 4),
     PV with the probabilities cast to V's dtype.  m, l, the accumulator
     and the softmax stay float32; float32 inputs give float32 dots.
 
@@ -79,35 +81,38 @@ def _kv_range(iq, *, bq: int, bk: int, nk: int, causal: bool, window: int,
     return first, hi((iq * bq + bq - 1) // bk, nk - 1)
 
 
-def _head_block(H: int, KV: int, hd: int) -> tuple:
+def _head_block(H: int, KV: int, hd: int, hdv: int) -> tuple:
     """Fewest query heads per block (with the KV heads they read) whose
-    lane widths tile by 128, or span the array, reaching 256 lanes."""
+    lane widths, of q/k heads of ``hd`` and v heads of ``hdv``, tile by
+    128, or span the array, reaching 256 lanes."""
     group = H // KV
     for hb in (d for d in range(1, H) if H % d == 0):
         if hb % group and group % hb:
             continue
         kvb = max(1, hb // group)
         if hb * hd >= 2 * LANES and all(
-                n == total or n * hd % LANES == 0
-                for n, total in ((hb, H), (kvb, KV))):
+                n == total or n * w % LANES == 0
+                for n, total in ((hb, H), (kvb, KV)) for w in (hd, hdv)):
             return hb, kvb
     return H, KV                  # every head: the blocks span the arrays
 
 
-def _vmem_bytes(bq: int, bk: int, hb: int, kvb: int, hd: int,
+def _vmem_bytes(bq: int, bk: int, hb: int, kvb: int, hd: int, hdv: int,
                 itemsize: int) -> int:
-    io = 2 * (2 * bq * hb + 2 * bk * kvb) * hd * itemsize  # q, o, k, v x2
-    scratch = hb * bq * (max(hd, LANES) + 2 * LANES) * 4   # acc, m, l
+    io = 2 * (bq * hb + bk * kvb) * (hd + hdv) * itemsize  # q, o, k, v x2
+    scratch = hb * bq * (max(hdv, LANES) + 2 * LANES) * 4  # acc, m, l
     return io + scratch + 4 * bq * bk * 4                  # one head's s, p
 
 
 def plan(S: int, H: int, KV: int, hd: int, dtype, *, causal: bool = True,
          window: int = 0, block_q: int | None = None,
-         block_k: int | None = None) -> FlashPlan:
-    """Blocks for a ``[B, S, H, hd]`` forward from the shape alone."""
+         block_k: int | None = None, hdv: int | None = None) -> FlashPlan:
+    """Blocks for a ``[B, S, H, hd]`` forward from the shape alone; ``hdv``
+    is the v head dim where it differs from q/k's ``hd``."""
+    hdv = hdv or hd
     itemsize = jnp.dtype(dtype).itemsize
     sub = 8 * 4 // itemsize                  # sublane tile: 8 f32, 16 bf16
-    hb, kvb = _head_block(H, KV, hd)
+    hb, kvb = _head_block(H, KV, hd, hdv)
     target = MAX_BLOCK
     if causal and window:
         target = min(target, max(LANES, _round_up(window, LANES)))
@@ -118,7 +123,7 @@ def plan(S: int, H: int, KV: int, hd: int, dtype, *, causal: bool = True,
             b = min((c for c in (512, 256, 128) if c <= target),
                     key=lambda c: (_round_up(S, c), -c))
         if target == LANES or \
-                _vmem_bytes(b, b, hb, kvb, hd, itemsize) <= VMEM_BUDGET:
+                _vmem_bytes(b, b, hb, kvb, hd, hdv, itemsize) <= VMEM_BUDGET:
             break
         target = max(LANES, target // 2)
     bq = min(block_q, _round_up(S, sub)) if block_q else b
@@ -141,7 +146,8 @@ def _lanes(x, n: int):
 
 def _update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, masked: bool,
             q0, k0, scale: float, causal: bool, window: int, bq: int,
-            bk: int, seq_len: int, heads: int, group: int, hd: int):
+            bk: int, seq_len: int, heads: int, group: int, hd: int,
+            hdv: int):
     """One (q-block, k-block) pair for every head of the block."""
     if masked:
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -156,7 +162,7 @@ def _update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, masked: bool,
         q = q_ref[0, :, h * hd:(h + 1) * hd]
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
         k = k_ref[0, :, c * hd:(c + 1) * hd]
-        v = v_ref[0, :, c * hd:(c + 1) * hd]
+        v = v_ref[0, :, c * hdv:(c + 1) * hdv]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
@@ -167,14 +173,15 @@ def _update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, masked: bool,
         p = jnp.exp(s - _lanes(m_new, bk))               # [bq, bk]
         l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[h] = m_new
-        acc_ref[h] = acc_ref[h] * _lanes(alpha, hd) + jax.lax.dot_general(
+        acc_ref[h] = acc_ref[h] * _lanes(alpha, hdv) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale: float, causal: bool, window: int, bq: int, bk: int,
-            nk: int, seq_len: int, heads: int, group: int, hd: int):
+            nk: int, seq_len: int, heads: int, group: int, hd: int,
+            hdv: int):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
@@ -196,7 +203,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     update = functools.partial(
         _update, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, q0=q0, k0=k0,
         scale=scale, causal=causal, window=window, bq=bq, bk=bk,
-        seq_len=seq_len, heads=heads, group=group, hd=hd)
+        seq_len=seq_len, heads=heads, group=group, hd=hd, hdv=hdv)
 
     @pl.when(run & clean)
     def _whole():
@@ -210,8 +217,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         for h in range(heads):
             l = jnp.maximum(l_ref[h], 1e-30)
-            o_ref[0, :, h * hd:(h + 1) * hd] = (
-                acc_ref[h] / _lanes(l, hd)).astype(o_ref.dtype)
+            o_ref[0, :, h * hdv:(h + 1) * hdv] = (
+                acc_ref[h] / _lanes(l, hdv)).astype(o_ref.dtype)
 
 
 def _pad_seq(x, n: int):
@@ -220,23 +227,25 @@ def _pad_seq(x, n: int):
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int | None = None, block_k: int | None = None,
-                    interpret: bool = True):
-    """q [B, S, H, hd]; k, v [B, S, KV, hd] (KV divides H) -> [B, S, H, hd].
+                    scale: float | None = None, block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool = True):
+    """q, k [B, S, H or KV, hd]; v [B, S, KV, hdv] (KV divides H) ->
+    [B, S, H, hdv].  Scores are scaled by ``scale``, 1/sqrt(hd) by default.
 
     Blocks come from ``plan``; ``block_q`` / ``block_k`` override its
     sequence blocks."""
     B, S, H, hd = q.shape
-    KV = k.shape[2]
+    KV, hdv = k.shape[2], v.shape[3]
     group = H // KV
+    scale = 1.0 / hd ** 0.5 if scale is None else float(scale)
     pn = plan(S, H, KV, hd, q.dtype, causal=causal, window=window,
-              block_q=block_q, block_k=block_k)
+              block_q=block_q, block_k=block_k, hdv=hdv)
     bq, bk, hb, kvb, nq, nk = (pn.block_q, pn.block_k, pn.heads,
                                pn.kv_heads, pn.nq, pn.nk)
     # free views [B, S, heads*hd], padded to whole blocks
     qf = _pad_seq(q.reshape(B, S, H * hd), nq * bq)
     kf = _pad_seq(k.reshape(B, S, KV * hd), nk * bk)
-    vf = _pad_seq(v.reshape(B, S, KV * hd), nk * bk)
+    vf = _pad_seq(v.reshape(B, S, KV * hdv), nk * bk)
 
     def q_map(b, h, iq, ik):
         return b, iq, h
@@ -250,19 +259,19 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             h * hb // (group * kvb)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / hd ** 0.5, causal=causal,
+        functools.partial(_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bk=bk, nk=nk, seq_len=S,
-                          heads=hb, group=group, hd=hd),
+                          heads=hb, group=group, hd=hd, hdv=hdv),
         grid=(B, H // hb, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, hb * hd), q_map),
             pl.BlockSpec((1, bk, kvb * hd), kv_map),
-            pl.BlockSpec((1, bk, kvb * hd), kv_map),
+            pl.BlockSpec((1, bk, kvb * hdv), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, bq, hb * hd), q_map),
-        out_shape=jax.ShapeDtypeStruct((B, nq * bq, H * hd), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hb * hdv), q_map),
+        out_shape=jax.ShapeDtypeStruct((B, nq * bq, H * hdv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((hb, bq, hd), jnp.float32),
+            pltpu.VMEM((hb, bq, hdv), jnp.float32),
             pltpu.VMEM((hb, bq, LANES), jnp.float32),
             pltpu.VMEM((hb, bq, LANES), jnp.float32),
         ],
@@ -271,7 +280,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                  "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
-    return out[:, :S].reshape(B, S, H, hd)
+    return out[:, :S].reshape(B, S, H, hdv)
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref,

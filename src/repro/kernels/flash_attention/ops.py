@@ -17,13 +17,14 @@ from repro.kernels.flash_attention.flash_attention import (flash_attention,
 from repro.kernels.flash_attention.ref import attention_ref, decode_ref
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
+                                             "block_q", "block_k",
+                                             "interpret"))
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
-              block_q: int | None = None, block_k: int | None = None,
-              interpret: bool = True):
+              scale: float | None = None, block_q: int | None = None,
+              block_k: int | None = None, interpret: bool = True):
     return flash_attention(q, k, v, causal=causal, window=window,
-                           block_q=block_q, block_k=block_k,
+                           scale=scale, block_q=block_q, block_k=block_k,
                            interpret=interpret)
 
 
@@ -35,32 +36,36 @@ def decode(q, ck, cv, pos, *, window: int = 0, block_k: int = 128,
                         interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attention_grad(q, k, v, causal, window, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _attention_grad(q, k, v, causal, window, scale, interpret):
     return flash_attention(q, k, v, causal=causal, window=window,
-                           interpret=interpret)
+                           scale=scale, interpret=interpret)
 
 
-def _attention_grad_fwd(q, k, v, causal, window, interpret):
-    return _attention_grad(q, k, v, causal, window, interpret), (q, k, v)
+def _attention_grad_fwd(q, k, v, causal, window, scale, interpret):
+    return (_attention_grad(q, k, v, causal, window, scale, interpret),
+            (q, k, v))
 
 
-def _attention_grad_bwd(causal, window, interpret, res, g):
+def _attention_grad_bwd(causal, window, scale, interpret, res, g):
     q, k, v = res
     _, vjp = jax.vjp(
         lambda qq, kk, vv: attention_ref(qq, kk, vv, causal=causal,
-                                         window=window), q, k, v)
+                                         window=window, scale=scale),
+        q, k, v)
     return vjp(g)
 
 
 _attention_grad.defvjp(_attention_grad_fwd, _attention_grad_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
+                                             "interpret"))
 def attention_grad(q, k, v, *, causal: bool = True, window: int = 0,
-                   interpret: bool = True):
-    """Flash forward with a reference-math VJP (safe under value_and_grad)."""
-    return _attention_grad(q, k, v, causal, window, interpret)
+                   scale: float | None = None, interpret: bool = True):
+    """Flash forward with a reference-math VJP (safe under value_and_grad);
+    v may have its own head dim, and ``scale`` defaults to 1/sqrt(hd)."""
+    return _attention_grad(q, k, v, causal, window, scale, interpret)
 
 
 __all__ = ["attention", "attention_grad", "attention_ref", "decode",

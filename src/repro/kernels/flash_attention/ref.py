@@ -7,15 +7,18 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
-    """q [B, S, H, hd]; k, v [B, S, KV, hd] (KV divides H).  fp32 math."""
+def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
+                  scale: float | None = None):
+    """q, k [B, S, H or KV, hd]; v [B, S, KV, hdv] (KV divides H).  fp32
+    math; scores scaled by ``scale``, 1/sqrt(hd) by default."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     rep = H // KV
     k = jnp.repeat(k, rep, axis=2)
     v = jnp.repeat(v, rep, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(hd))
+                   k.astype(jnp.float32))
+    s = s / jnp.sqrt(jnp.float32(hd)) if scale is None else s * scale
     qi = jnp.arange(S)[:, None]
     kj = jnp.arange(S)[None, :]
     if causal:

@@ -64,17 +64,47 @@ def activation(name: str, x):
 
 
 # ---------------------------------------------------------------------- RoPE
-def _rope_cos_sin(positions, half_dim: int, theta: float):
+def rope_inv_freq(half_dim: int, theta: float, yarn=None):
+    """The rotary frequencies of ``half_dim`` pairs; with a ``configs.Yarn``
+    the YaRN blend: interpolated by ``factor`` below the correction range,
+    kept above it, and ramped linearly between."""
+    if yarn is None:
+        return 1.0 / (theta ** (jnp.arange(half_dim, dtype=jnp.float32)
+                                / half_dim))
+    f = 1.0 / theta ** (np.arange(half_dim, dtype=np.float64) / half_dim)
+    dim = 2 * half_dim
+
+    def corr(rot):     # the pair index that turns ``rot`` times
+        return dim * np.log(yarn.original_max_position
+                            / (rot * 2 * np.pi)) / (2 * np.log(theta))
+    low = max(np.floor(corr(yarn.beta_fast)), 0)
+    high = min(np.ceil(corr(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half_dim) - low) / max(high - low, 1e-3), 0, 1)
+    return jnp.asarray(f / yarn.factor * ramp + f * (1 - ramp), jnp.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_cos_sin(positions, half_dim: int, theta: float, yarn=None):
     """positions [...]; returns cos/sin of shape positions.shape + (half_dim,)."""
-    freqs = 1.0 / (theta ** (jnp.arange(half_dim, dtype=jnp.float32) / half_dim))
+    freqs = rope_inv_freq(half_dim, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rope(x, positions, theta: float):
-    """x [B, S, H, hd]; positions [B, S] -> rotated x (llama half-split style)."""
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x [B, S, H, hd]; positions [B, S] -> rotated x (llama half-split
+    style).  A ``configs.Yarn`` scales the frequencies, and cos and sin by
+    ``mscale(mscale) / mscale(mscale_all_dim)`` where that is not 1."""
     hd = x.shape[-1]
-    cos, sin = _rope_cos_sin(positions, hd // 2, theta)     # [B, S, hd/2]
+    cos, sin = _rope_cos_sin(positions, hd // 2, theta, yarn)   # [B, S, hd/2]
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     cos = cos[:, :, None, :].astype(jnp.float32)
     sin = sin[:, :, None, :].astype(jnp.float32)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -84,12 +84,24 @@ def _layer_init(key, cfg: ModelConfig, sig, dtype):
     return p
 
 
+def _no_moe():
+    return {"aux": jnp.float32(0.0), "dropped": jnp.float32(0.0),
+            "held_load": jnp.float32(0.0)}
+
+
+def _add_moe(a, b):
+    """Two layers' MoE stats: losses and drops add, the load is the most."""
+    return {"aux": a["aux"] + b["aux"], "dropped": a["dropped"] + b["dropped"],
+            "held_load": jnp.maximum(a["held_load"], b["held_load"])}
+
+
 def _layer_forward(p, cfg: ModelConfig, sig, x, positions, state=None,
                    window_override=0):
-    """Full-sequence forward for one layer.  Returns (x, aux, new_state).
-    state is only used/returned for stateful kinds (cache build in prefill)."""
+    """Full-sequence forward for one layer.  Returns (x, moe_stats,
+    new_state), the stats of ``moe_apply`` (zeros without a MoE).  state is
+    only used/returned for stateful kinds (cache build in prefill)."""
     kind, use_moe = sig
-    aux = jnp.float32(0.0)
+    stats = _no_moe()
     with jax.named_scope("norm"):
         h = norm_apply(cfg.norm, p["ln1"], x, cfg.norm_eps)
     new_state = None
@@ -113,16 +125,16 @@ def _layer_forward(p, cfg: ModelConfig, sig, x, positions, state=None,
         out2, shift_cm = rwkv_mod.channel_mix_forward(p["mixer"], h2, cfg)
         new_state = {"S": new_state_tm["S"], "shift_tm": new_state_tm["shift"],
                      "shift_cm": shift_cm}
-        return x + out2, aux, new_state
+        return x + out2, stats, new_state
     x = x + out
     with jax.named_scope("norm"):
         h = norm_apply(cfg.norm, p["ln2"], x, cfg.norm_eps)
     with jax.named_scope("mlp"):
         if use_moe:
-            out, aux = moe_apply(p["moe"], h, cfg)
+            out, stats = moe_apply(p["moe"], h, cfg)
         else:
             out = mlp_apply(p["mlp"], h, cfg.act)
-    return x + out, aux, new_state
+    return x + out, stats, new_state
 
 
 def _layer_decode(p, cfg: ModelConfig, sig, x, pos, cache, window_override=0,
@@ -227,7 +239,9 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
             return_cache: bool = False, cache_len: int = 0,
             remat: bool = False, unroll: bool = False,
             window_override: int = 0):
-    """Full-sequence forward.  Returns (logits, aux, caches|None).
+    """Full-sequence forward.  Returns (logits, moe_stats, caches|None):
+    the MoE layers' balance loss and drops summed (``aux``, ``dropped``)
+    and their most loaded held expert (``held_load``).
 
     tokens [B, S] int32.  positions: [B, S] (or [B, 3, S] for M-RoPE).
     vision_embeds [B, P, d]: merged into the leading P token slots (vlm stub).
@@ -246,7 +260,7 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         if cfg.mrope_sections:
             positions = jnp.broadcast_to(positions[:, None], (B, 3, S))
-    aux_total = jnp.float32(0.0)
+    moe_total = _no_moe()
     caches: List[Any] = []
 
     seg_i = 0
@@ -254,24 +268,24 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
         p_seg = params["segments"][seg_i]
         seg_i += 1
         if seg[0] == "plain":
-            x, aux, st = _layer_forward(p_seg, cfg, seg[1], x, positions,
-                                        window_override=window_override)
-            aux_total = aux_total + aux
+            x, stats, st = _layer_forward(p_seg, cfg, seg[1], x, positions,
+                                          window_override=window_override)
+            moe_total = _add_moe(moe_total, stats)
             if return_cache:
                 caches.append(st)
         else:
             _, pattern, n_groups = seg
 
             def body(carry, g_params, _pattern=pattern):
-                xc, auxc = carry
+                xc, moec = carry
                 sts = []
                 for j, sig in enumerate(_pattern):
-                    xc, aux_j, st_j = _layer_forward(
+                    xc, stats_j, st_j = _layer_forward(
                         g_params[j], cfg, sig, xc, positions,
                         window_override=window_override)
-                    auxc = auxc + aux_j
+                    moec = _add_moe(moec, stats_j)
                     sts.append(st_j)
-                return (xc, auxc), tuple(sts)
+                return (xc, moec), tuple(sts)
 
             if remat and not return_cache:
                 body = jax.checkpoint(body)   # per-layer-group activation remat
@@ -279,21 +293,21 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
                 # analysis-only path: XLA cost_analysis counts while-loop
                 # bodies once, so the roofline dry-run unrolls the stack
                 seg_states_l = []
-                carry = (x, aux_total)
+                carry = (x, moe_total)
                 for gi in range(n_groups):
                     g_params = jax.tree.map(lambda a, _g=gi: a[_g], p_seg)
                     carry, sts = body(carry, g_params)
                     seg_states_l.append(sts)
-                (x, aux_total) = carry
+                (x, moe_total) = carry
                 seg_states = jax.tree.map(
                     lambda *xs: jnp.stack(xs), *seg_states_l)
             else:
-                (x, aux_total), seg_states = jax.lax.scan(
-                    body, (x, aux_total), p_seg)
+                (x, moe_total), seg_states = jax.lax.scan(
+                    body, (x, moe_total), p_seg)
             if return_cache:
                 caches.append(seg_states)
     logits = _head(params, cfg, x, compute_dtype)
-    return logits, aux_total, (caches if return_cache else None)
+    return logits, moe_total, (caches if return_cache else None)
 
 
 def _head(params, cfg: ModelConfig, x, compute_dtype):
@@ -309,15 +323,21 @@ def _head(params, cfg: ModelConfig, x, compute_dtype):
 def loss_fn(params, cfg: ModelConfig, batch, compute_dtype=jnp.bfloat16,
             remat: bool = False, unroll: bool = False):
     """Next-token CE + MoE aux.  batch: {tokens, labels[, mask, positions,
-    vision_embeds]}."""
-    logits, aux, _ = forward(
+    vision_embeds]}.  Metrics: ``ce``, ``aux``, and for a MoE
+    ``moe_dropped`` (assignments left uncomputed) and ``moe_held_load``
+    (the most tokens a held expert got over the balanced T*K/E)."""
+    logits, moe, _ = forward(
         params, cfg, batch["tokens"], positions=batch.get("positions"),
         vision_embeds=batch.get("vision_embeds"), compute_dtype=compute_dtype,
         remat=remat, unroll=unroll)
     with jax.named_scope("loss"):
         ce = cross_entropy(logits, batch["labels"], batch.get("mask"),
                            vocab_size=cfg.vocab_size)
-    return ce + aux, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": moe["aux"]}
+    if cfg.moe:
+        metrics.update(moe_dropped=moe["dropped"],
+                       moe_held_load=moe["held_load"])
+    return ce + moe["aux"], metrics
 
 
 # --------------------------------------------------------------------- decode

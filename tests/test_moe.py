@@ -28,7 +28,7 @@ def test_no_drop_equals_dense_computation():
     key = jax.random.PRNGKey(0)
     p = moe_init(key, cfg)
     x = jax.random.normal(jax.random.fold_in(key, 1), (2, 6, cfg.d_model))
-    out, aux = moe_apply(p, x, cfg)
+    out, stats = moe_apply(p, x, cfg)
 
     # reference: dense evaluation of every expert for every token
     xt = x.reshape(-1, cfg.d_model)
@@ -44,7 +44,8 @@ def test_no_drop_equals_dense_computation():
                      gate)
     np.testing.assert_allclose(np.asarray(out.reshape(-1, cfg.d_model)),
                                np.asarray(ref), atol=1e-4)
-    assert float(aux) >= 0
+    assert float(stats["aux"]) >= 0
+    assert float(stats["dropped"]) == 0
 
 
 def test_capacity_drops_are_bounded():
@@ -76,12 +77,12 @@ def test_aux_loss_penalizes_imbalance():
     # uniform router
     p_uniform = dict(p)
     p_uniform["router"] = {"w": jnp.zeros_like(p["router"]["w"])}
-    _, aux_uniform = moe_apply(p_uniform, x, cfg)
+    aux_uniform = moe_apply(p_uniform, x, cfg)[1]["aux"]
     # collapsed router: huge bias toward expert 0
     w = jnp.zeros_like(p["router"]["w"]).at[:, 0].set(100.0)
     p_collapsed = dict(p)
     p_collapsed["router"] = {"w": w}
-    _, aux_collapsed = moe_apply(p_collapsed, x, cfg)
+    aux_collapsed = moe_apply(p_collapsed, x, cfg)[1]["aux"]
     assert float(aux_collapsed) > float(aux_uniform)
 
 
@@ -92,8 +93,8 @@ def test_moe_grads_flow_to_experts_and_router():
     x = jax.random.normal(jax.random.fold_in(key, 7), (2, 6, cfg.d_model))
 
     def loss(pp):
-        out, aux = moe_apply(pp, x, cfg)
-        return jnp.sum(out ** 2) + aux
+        out, stats = moe_apply(pp, x, cfg)
+        return jnp.sum(out ** 2) + stats["aux"]
 
     g = jax.grad(loss)(p)
     assert float(jnp.abs(g["w_gate"]).sum()) > 0

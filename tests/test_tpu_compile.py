@@ -1,8 +1,9 @@
 """The main path's Pallas kernels compile for a TPU v5e chip.
 
 Each test compiles one kernel at the widths the system runs it (StableLM-2
-attention, the ``[R, 256]`` codec rows, an untied LM head's vocab-long
-EF rows) for a described ``v5e:2x2`` topology and checks that the program
+attention, DeepSeek-V2's latent attention and its training step, the
+``[R, 256]`` codec rows, an untied LM head's vocab-long EF rows) for a
+described ``v5e:2x2`` topology and checks that the program
 calls the Mosaic kernel (``tpu_custom_call``).  Nothing runs: the TPU
 compiler is installed on CPU hosts, and a kernel Mosaic refuses (a bad
 relayout, an op it cannot legalize, a block over the VMEM limit) fails
@@ -92,6 +93,62 @@ def test_attention_grad_fwd_bwd(one_chip):
         # value_and_grad: the forward value keeps the kernel live (the
         # VJP replays the reference math)
         _assert_kernel(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_flash_attention_mla(one_chip):
+    """DeepSeek-V2's latent attention at its cell's 2 rows of 4096 tokens:
+    q/k heads of 192, v heads of 128, two heads a step, explicit scale."""
+    q = _shape(one_chip, (2, 4096, 16, 192), jnp.bfloat16)
+    v = _shape(one_chip, (2, 4096, 16, 128), jnp.bfloat16)
+    assert plan(4096, 16, 16, 192, jnp.bfloat16, hdv=128).heads == 2
+    _assert_kernel(functools.partial(flash_attention, causal=True,
+                                     scale=0.1352, interpret=False), q, q, v)
+
+
+def test_deepseek_train_step(topo, one_chip, monkeypatch):
+    """The DeepSeek-V2 cell's whole training step (1 dense + 4 MoE layers
+    holding 8 of 64 experts, vocabulary 12800, 2 rows of 4096 tokens, bf16
+    compute) fits one chip, with the flash forward and the held experts'
+    grouped matmuls as Mosaic kernels."""
+    import dataclasses
+
+    import repro.models.mla as mla
+    import repro.models.moe as moe
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.train import Strategy
+
+    for mod in (mla, moe):
+        monkeypatch.setattr(mod, "kernel_interpret", lambda: False)
+        monkeypatch.setattr(mod, "resolve_backend", lambda b: "kernel")
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                              num_layers=5, vocab_size=12800,
+                              experts_held=(0, 8))
+    model = build_model(cfg)
+
+    def grad_fn(p, b):
+        (loss, _), g = jax.value_and_grad(
+            lambda q: model.loss_fn(q, b), has_aux=True)(p)
+        return loss, g
+    engine = Strategy.parse("bsp/allreduce/none@1", lr=0.1).build(
+        grad_fn, devices=[topo.devices[0]]).inner
+    rep, per = (NamedSharding(engine.mesh, P()),
+                NamedSharding(engine.mesh, P("workers")))
+    params = jax.tree.map(lambda a: _shape(rep, a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {k: _shape(per, (1, 2, 4096), jnp.int32)
+             for k in ("tokens", "labels")}
+    compiled = engine._build_step(params).lower(
+        params, None, batch, _shape(per, (1, 2), jnp.uint32),
+        _shape(per, (1,))).compile()
+    mem = compiled.memory_analysis()
+    print(f"deepseek step: arguments {mem.argument_size_in_bytes / 1e9:.2f} "
+          f"GB, temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, peak "
+          f"{mem.peak_memory_in_bytes / 1e9:.2f} GB")
+    text = compiled.as_text()
+    assert "jit(attention_grad)/pallas_call" in text
+    assert "/moe/experts/jit(gmm)/pallas_call" in text
+    assert "jit(tgmm)/pallas_call" in text
 
 
 @pytest.mark.parametrize("window", [0, 512], ids=["full", "window"])
