@@ -31,6 +31,22 @@ Training / prefill forward (``flash_attention``)
     PV with the probabilities cast to V's dtype.  m, l, the accumulator
     and the softmax stay float32; float32 inputs give float32 dots.
 
+Training backward (``flash_attention_bwd``)
+    Under differentiation the forward (``flash_attention_fwd``) also writes
+    each row's float32 log-sum-exp ``m + log l``, ``[B, H, S]``; the
+    primal and the serving paths keep the one-output kernel.  The backward
+    is the FlashAttention-2 split on the forward's tiling: with
+    ``D = rowsum(dO * O)`` in float32, a dK/dV kernel (k-block outer, the
+    q-blocks that see it streamed innermost, keys on the sublanes so the
+    lse and D rows broadcast as they are) and a dQ kernel (q-block outer,
+    k-blocks innermost) each recompute ``P = exp(s - lse)`` and
+    ``dS = P * (dP - D)`` block by block, accumulating in float32 VMEM.
+    GQA sums dK/dV over each KV head's query group inside the kernel, over
+    head blocks too where a group spans several.  ``plan(...,
+    backward=True)`` gives its blocks and pairs, against a VMEM budget of
+    its own; pairs are skipped, their DMA elided and edges masked as in
+    the forward.  P and dS reach the MXU in the input dtype.
+
 One-token decode (``flash_decode``)
     Streams ``[B, L, KV, hd]`` cache blocks through VMEM with the same
     online-softmax scratch; see its docstring.
@@ -52,6 +68,8 @@ NEG_INF = -1e30
 LANES = 128
 MAX_BLOCK = 512                 # longest sequence block the plan picks
 VMEM_BUDGET = 12 * 2 ** 20      # bytes one forward grid step may hold
+BWD_VMEM_BUDGET = 24 * 2 ** 20  # bytes one backward grid step may hold
+BWD_VMEM_LIMIT = 64 * 2 ** 20   # the backward kernels' scoped VMEM limit
 
 
 def _round_up(n: int, m: int) -> int:
@@ -60,7 +78,7 @@ def _round_up(n: int, m: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class FlashPlan:
-    """The forward kernel's schedule for one input shape."""
+    """The forward's or the backward's schedule for one input shape."""
     block_q: int
     block_k: int
     heads: int       # query heads per grid step
@@ -81,6 +99,17 @@ def _kv_range(iq, *, bq: int, bk: int, nk: int, causal: bool, window: int,
     return first, hi((iq * bq + bq - 1) // bk, nk - 1)
 
 
+def _q_range(ik, *, bq: int, bk: int, nq: int, causal: bool, window: int,
+             hi=min):
+    """First and last q-block that attends to k-block ``ik``: the same
+    pairs as ``_kv_range``, enumerated by k-block."""
+    if not causal:
+        return 0, nq - 1
+    last = hi((ik * bk + bk + window - 2) // bq, nq - 1) if window \
+        else nq - 1
+    return ik * bk // bq, last
+
+
 def _head_block(H: int, KV: int, hd: int, hdv: int) -> tuple:
     """Fewest query heads per block (with the KV heads they read) whose
     lane widths, of q/k heads of ``hd`` and v heads of ``hdv``, tile by
@@ -98,21 +127,32 @@ def _head_block(H: int, KV: int, hd: int, hdv: int) -> tuple:
 
 
 def _vmem_bytes(bq: int, bk: int, hb: int, kvb: int, hd: int, hdv: int,
-                itemsize: int) -> int:
+                itemsize: int, backward: bool) -> int:
+    """What one grid step holds: double-buffered blocks, float32 scratch
+    and one head's [bq, bk] temporaries."""
     io = 2 * (bq * hb + bk * kvb) * (hd + hdv) * itemsize  # q, o, k, v x2
-    scratch = hb * bq * (max(hdv, LANES) + 2 * LANES) * 4  # acc, m, l
-    return io + scratch + 4 * bq * bk * 4                  # one head's s, p
+    if not backward:
+        scratch = hb * bq * (max(hdv, LANES) + 2 * LANES) * 4  # acc, m, l
+        return io + scratch + 4 * bq * bk * 4                  # s, p
+    # dK/dV: + dk, dv blocks and their float32 accumulators, the lse and D
+    # rows; dQ's blocks and accumulator are no larger; s, p, dp, ds
+    io += 2 * bk * kvb * (hd + hdv) * itemsize + 2 * 2 * hb * 8 * bq * 4
+    scratch = kvb * bk * (max(hd, LANES) + max(hdv, LANES)) * 4
+    return io + scratch + 8 * bq * bk * 4
 
 
 def plan(S: int, H: int, KV: int, hd: int, dtype, *, causal: bool = True,
          window: int = 0, block_q: int | None = None,
-         block_k: int | None = None, hdv: int | None = None) -> FlashPlan:
-    """Blocks for a ``[B, S, H, hd]`` forward from the shape alone; ``hdv``
-    is the v head dim where it differs from q/k's ``hd``."""
+         block_k: int | None = None, hdv: int | None = None,
+         backward: bool = False) -> FlashPlan:
+    """Blocks for a ``[B, S, H, hd]`` forward, or its backward, from the
+    shape alone; ``hdv`` is the v head dim where it differs from q/k's
+    ``hd``.  The backward's pairs are enumerated by k-block."""
     hdv = hdv or hd
     itemsize = jnp.dtype(dtype).itemsize
     sub = 8 * 4 // itemsize                  # sublane tile: 8 f32, 16 bf16
     hb, kvb = _head_block(H, KV, hd, hdv)
+    budget = BWD_VMEM_BUDGET if backward else VMEM_BUDGET
     target = MAX_BLOCK
     if causal and window:
         target = min(target, max(LANES, _round_up(window, LANES)))
@@ -122,16 +162,19 @@ def plan(S: int, H: int, KV: int, hd: int, dtype, *, causal: bool = True,
         else:   # least padding, then the largest block
             b = min((c for c in (512, 256, 128) if c <= target),
                     key=lambda c: (_round_up(S, c), -c))
-        if target == LANES or \
-                _vmem_bytes(b, b, hb, kvb, hd, hdv, itemsize) <= VMEM_BUDGET:
+        if target == LANES or _vmem_bytes(b, b, hb, kvb, hd, hdv, itemsize,
+                                          backward) <= budget:
             break
         target = max(LANES, target // 2)
     bq = min(block_q, _round_up(S, sub)) if block_q else b
     bk = min(block_k, _round_up(S, sub)) if block_k else b
     nq, nk = -(-S // bq), -(-S // bk)
-    kw = dict(bq=bq, bk=bk, nk=nk, causal=causal, window=window)
-    pairs = sum(last - first + 1 for first, last in
-                (_kv_range(iq, **kw) for iq in range(nq)))
+    kw = dict(bq=bq, bk=bk, causal=causal, window=window)
+    if backward:
+        ranges = (_q_range(ik, nq=nq, **kw) for ik in range(nk))
+    else:
+        ranges = (_kv_range(iq, nk=nk, **kw) for iq in range(nq))
+    pairs = sum(last - first + 1 for first, last in ranges)
     return FlashPlan(bq, bk, hb, kvb, nq, nk, pairs)
 
 
@@ -144,27 +187,72 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+NT = (((1,), (1,)), ((), ()))       # a @ b.T
+NN = (((1,), (0,)), ((), ()))       # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _pair(update, run, q0, k0, *, bq: int, bk: int, seq_len: int,
+          causal: bool, window: int):
+    """Runs ``update`` on a block pair that computes: unmasked where every
+    pair of it is visible, masked where it straddles the diagonal, the
+    window's edge or the padded key tail."""
+    clean = k0 + bk <= seq_len
+    if causal:
+        clean &= k0 + bk - 1 <= q0
+        if window:
+            clean &= k0 > q0 + bq - 1 - window
+
+    @pl.when(run & clean)
+    def _whole():
+        update(masked=False)
+
+    @pl.when(run & jnp.logical_not(clean))
+    def _edge():
+        update(masked=True)
+
+
+def _mask(q0, k0, shape, q_axis: int, *, seq_len: int, causal: bool,
+          window: int):
+    """Which (query, key) pairs of a [bq, bk] (``q_axis`` 0) or [bk, bq]
+    (1) block attend: not the padded key tail, and within the causal
+    window."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    mask = kpos < seq_len
+    if causal:
+        mask &= kpos <= qpos
+        if window:
+            mask &= kpos > qpos - window
+    return mask
+
+
+def _head(ref, h: int, w: int):
+    """Head ``h`` (``w`` lanes wide) of a ``[1, rows, heads * w]`` block."""
+    return ref[0, :, h * w:(h + 1) * w]
+
+
+def _scaled(q, scale: float):
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
 def _update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, masked: bool,
             q0, k0, scale: float, causal: bool, window: int, bq: int,
             bk: int, seq_len: int, heads: int, group: int, hd: int,
             hdv: int):
     """One (q-block, k-block) pair for every head of the block."""
     if masked:
-        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = kpos < seq_len                            # key padding
-        if causal:
-            mask &= kpos <= qpos
-            if window:
-                mask &= kpos > qpos - window
+        mask = _mask(q0, k0, (bq, bk), 0, seq_len=seq_len, causal=causal,
+                     window=window)
     for h in range(heads):
         c = h // group                                   # its KV head
-        q = q_ref[0, :, h * hd:(h + 1) * hd]
-        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-        k = k_ref[0, :, c * hd:(c + 1) * hd]
-        v = v_ref[0, :, c * hdv:(c + 1) * hdv]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        q = _scaled(_head(q_ref, h, hd), scale)
+        k, v = _head(k_ref, c, hd), _head(v_ref, c, hdv)
+        s = _dot(q, k, NT)
         if masked:
             s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[h]                                # [bq, 128]
@@ -173,15 +261,15 @@ def _update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, masked: bool,
         p = jnp.exp(s - _lanes(m_new, bk))               # [bq, bk]
         l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[h] = m_new
-        acc_ref[h] = acc_ref[h] * _lanes(alpha, hdv) + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[h] = acc_ref[h] * _lanes(alpha, hdv) + _dot(
+            p.astype(v.dtype), v, NN)
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            scale: float, causal: bool, window: int, bq: int, bk: int,
-            nk: int, seq_len: int, heads: int, group: int, hd: int,
-            hdv: int):
+def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, causal: bool,
+            window: int, bq: int, bk: int, nk: int, seq_len: int,
+            heads: int, group: int, hd: int, hdv: int):
+    lse_ref = rest[0] if len(rest) == 4 else None
+    acc_ref, m_ref, l_ref = rest[-3:]
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
@@ -194,24 +282,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q0, k0 = iq * bq, ik * bk
-    run = (ik >= first) & (ik <= last)
-    clean = k0 + bk <= seq_len                # every pair of it is visible
-    if causal:
-        clean &= k0 + bk - 1 <= q0
-        if window:
-            clean &= k0 > q0 + bq - 1 - window
     update = functools.partial(
         _update, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, q0=q0, k0=k0,
         scale=scale, causal=causal, window=window, bq=bq, bk=bk,
         seq_len=seq_len, heads=heads, group=group, hd=hd, hdv=hdv)
-
-    @pl.when(run & clean)
-    def _whole():
-        update(masked=False)
-
-    @pl.when(run & jnp.logical_not(clean))
-    def _edge():
-        update(masked=True)
+    _pair(update, (ik >= first) & (ik <= last), q0, k0, bq=bq, bk=bk,
+          seq_len=seq_len, causal=causal, window=window)
 
     @pl.when(ik == last)
     def _finalize():
@@ -219,21 +295,40 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             l = jnp.maximum(l_ref[h], 1e-30)
             o_ref[0, :, h * hdv:(h + 1) * hdv] = (
                 acc_ref[h] / _lanes(l, hdv)).astype(o_ref.dtype)
+            if lse_ref is not None:      # one row of bq lanes a head
+                lse_ref[0, h] = (m_ref[h] + jnp.log(l)).T[:1]
 
 
-def _pad_seq(x, n: int):
-    return x if x.shape[1] == n else \
-        jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
+def _pad_seq(x, n: int, axis: int = 1):
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, n - x.shape[axis])
+    return x if x.shape[axis] == n else jnp.pad(x, pad)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    scale: float | None = None, block_q: int | None = None,
-                    block_k: int | None = None, interpret: bool = True):
-    """q, k [B, S, H or KV, hd]; v [B, S, KV, hdv] (KV divides H) ->
-    [B, S, H, hdv].  Scores are scaled by ``scale``, 1/sqrt(hd) by default.
+def _q_major_maps(*, bq: int, bk: int, nk: int, causal: bool, window: int,
+                  hb: int, group: int, kvb: int):
+    """Index maps of a grid ``(B, head blocks, nq, nk)``: q-side blocks of
+    ``[B, S, heads * w]``, K/V blocks, and per-row ``[B, H, 1, S]``
+    blocks."""
+    def q_map(b, h, iq, ik):
+        return b, iq, h
 
-    Blocks come from ``plan``; ``block_q`` / ``block_k`` override its
-    sequence blocks."""
+    def kv_map(b, h, iq, ik):
+        # a skipped step maps to a block the q-block needs: no new DMA
+        first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
+                                window=window, lo=jnp.maximum,
+                                hi=jnp.minimum)
+        return b, jnp.minimum(jnp.maximum(ik, first), last), \
+            h * hb // (group * kvb)
+
+    def rows_map(b, h, iq, ik):
+        return b, h, 0, iq
+
+    return q_map, kv_map, rows_map
+
+
+def _forward(q, k, v, *, causal: bool, window: int, scale, block_q,
+             block_k, interpret: bool, lse: bool):
     B, S, H, hd = q.shape
     KV, hdv = k.shape[2], v.shape[3]
     group = H // KV
@@ -247,17 +342,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kf = _pad_seq(k.reshape(B, S, KV * hd), nk * bk)
     vf = _pad_seq(v.reshape(B, S, KV * hdv), nk * bk)
 
-    def q_map(b, h, iq, ik):
-        return b, iq, h
-
-    def kv_map(b, h, iq, ik):
-        # a skipped step maps to a block the q-block needs: no new DMA
-        first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
-                                window=window, lo=jnp.maximum,
-                                hi=jnp.minimum)
-        return b, jnp.minimum(jnp.maximum(ik, first), last), \
-            h * hb // (group * kvb)
-
+    q_map, kv_map, rows_map = _q_major_maps(
+        bq=bq, bk=bk, nk=nk, causal=causal, window=window, hb=hb,
+        group=group, kvb=kvb)
+    out_specs = pl.BlockSpec((1, bq, hb * hdv), q_map)
+    out_shape = jax.ShapeDtypeStruct((B, nq * bq, H * hdv), q.dtype)
+    if lse:     # [B, H, 1, S]: each head's rows on the lanes
+        out_specs = [out_specs, pl.BlockSpec((1, hb, 1, bq), rows_map)]
+        out_shape = [out_shape, jax.ShapeDtypeStruct((B, H, 1, nq * bq),
+                                                     jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bk=bk, nk=nk, seq_len=S,
@@ -268,8 +361,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pl.BlockSpec((1, bk, kvb * hd), kv_map),
             pl.BlockSpec((1, bk, kvb * hdv), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, bq, hb * hdv), q_map),
-        out_shape=jax.ShapeDtypeStruct((B, nq * bq, H * hdv), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((hb, bq, hdv), jnp.float32),
             pltpu.VMEM((hb, bq, LANES), jnp.float32),
@@ -280,7 +373,211 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                  "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
-    return out[:, :S].reshape(B, S, H, hdv)
+    if not lse:
+        return out[:, :S].reshape(B, S, H, hdv)
+    return out[0][:, :S].reshape(B, S, H, hdv), out[1][:, :, 0, :S]
+
+
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                    scale: float | None = None, block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool = True):
+    """q, k [B, S, H or KV, hd]; v [B, S, KV, hdv] (KV divides H) ->
+    [B, S, H, hdv].  Scores are scaled by ``scale``, 1/sqrt(hd) by default.
+
+    Blocks come from ``plan``; ``block_q`` / ``block_k`` override its
+    sequence blocks."""
+    return _forward(q, k, v, causal=causal, window=window, scale=scale,
+                    block_q=block_q, block_k=block_k, interpret=interpret,
+                    lse=False)
+
+
+def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
+                        scale: float | None = None, interpret: bool = True):
+    """The training forward: ``flash_attention``'s output and each row's
+    float32 log-sum-exp of its scaled scores, ``[B, H, S]``, which
+    ``flash_attention_bwd`` takes back."""
+    return _forward(q, k, v, causal=causal, window=window, scale=scale,
+                    block_q=None, block_k=None, interpret=interpret,
+                    lse=True)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref, dv_ref,
+                dk_acc, dv_acc, *, scale: float, causal: bool, window: int,
+                bq: int, bk: int, nq: int, nsub: int, seq_len: int,
+                heads: int, group: int, hd: int, hdv: int):
+    """dK and dV of one k-block, summed over the q-blocks that see it
+    (innermost) and, where a KV head's query group spans several head
+    blocks, over those (``nsub``).  Keys on the sublanes, queries on the
+    lanes: the lse and D rows broadcast down the sublanes, and every
+    matmul takes its operands as they are."""
+    ik, j, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    first, last = _q_range(ik, bq=bq, bk=bk, nq=nq, causal=causal,
+                           window=window, hi=jnp.minimum)
+
+    @pl.when((j == 0) & (iq == 0))
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    q0, k0 = iq * bq, ik * bk
+
+    def update(masked: bool):
+        if masked:
+            mask = _mask(q0, k0, (bk, bq), 1, seq_len=seq_len,
+                         causal=causal, window=window)
+        for h in range(heads):
+            c = h // group
+            q = _scaled(_head(q_ref, h, hd), scale)
+            do = _head(do_ref, h, hdv)
+            st = _dot(_head(k_ref, c, hd), q, NT)        # [bk, bq]
+            if masked:
+                st = jnp.where(mask, st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, h])
+            dv_acc[c] += _dot(pt.astype(do.dtype), do, NN)
+            dpt = _dot(_head(v_ref, c, hdv), do, NT)
+            dst = pt * (dpt - d_ref[0, h])
+            dk_acc[c] += _dot(dst.astype(q.dtype), q, NN)   # q carries scale
+
+    _pair(update, (iq >= first) & (iq <= last), q0, k0, bq=bq, bk=bk,
+          seq_len=seq_len, causal=causal, window=window)
+
+    @pl.when((j == nsub - 1) & (iq == nq - 1))
+    def _finalize():
+        for c in range(dk_acc.shape[0]):
+            dk_ref[0, :, c * hd:(c + 1) * hd] = dk_acc[c].astype(dk_ref.dtype)
+            dv_ref[0, :, c * hdv:(c + 1) * hdv] = \
+                dv_acc[c].astype(dv_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref, dq_acc,
+               *, scale: float, causal: bool, window: int, bq: int, bk: int,
+               nk: int, seq_len: int, heads: int, group: int, hd: int,
+               hdv: int):
+    """dQ of one q-block, summed over the k-blocks it sees (innermost)."""
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    first, last = _kv_range(iq, bq=bq, bk=bk, nk=nk, causal=causal,
+                            window=window, lo=jnp.maximum, hi=jnp.minimum)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    q0, k0 = iq * bq, ik * bk
+
+    def update(masked: bool):
+        if masked:
+            mask = _mask(q0, k0, (bq, bk), 0, seq_len=seq_len,
+                         causal=causal, window=window)
+        for h in range(heads):
+            c = h // group
+            k = _head(k_ref, c, hd)
+            s = _dot(_scaled(_head(q_ref, h, hd), scale), k, NT)
+            if masked:
+                s = jnp.where(mask, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[0, h, 0][:, None])     # [bq, bk]
+            dp = _dot(_head(do_ref, h, hdv), _head(v_ref, c, hdv), NT)
+            ds = p * (dp - d_ref[0, h, 0][:, None])
+            dq_acc[h] += _dot(ds.astype(k.dtype), k, NN)
+
+    _pair(update, (ik >= first) & (ik <= last), q0, k0, bq=bq, bk=bk,
+          seq_len=seq_len, causal=causal, window=window)
+
+    @pl.when(ik == last)
+    def _finalize():
+        for h in range(heads):
+            dq_ref[0, :, h * hd:(h + 1) * hd] = \
+                (dq_acc[h] * scale).astype(dq_ref.dtype)
+
+
+def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
+                        window: int = 0, scale: float | None = None,
+                        interpret: bool = True):
+    """dq, dk, dv of ``flash_attention`` at (q, k, v), from its output
+    ``o``, the ``lse`` of ``flash_attention_fwd`` and the output's
+    cotangent ``do``.  P = exp(s - lse) is recomputed block by block and
+    dS = P * (dP - D), D = rowsum(do * o); no [S, S] array is formed."""
+    B, S, H, hd = q.shape
+    KV, hdv = k.shape[2], v.shape[3]
+    group = H // KV
+    scale = 1.0 / hd ** 0.5 if scale is None else float(scale)
+    pn = plan(S, H, KV, hd, q.dtype, causal=causal, window=window, hdv=hdv,
+              backward=True)
+    bq, bk, hb, kvb, nq, nk = (pn.block_q, pn.block_k, pn.heads,
+                               pn.kv_heads, pn.nq, pn.nk)
+    nsub = max(1, group // hb)          # head blocks of one KV head block
+    d = jnp.einsum("bshd,bshd->bhs", do.astype(jnp.float32),
+                   o.astype(jnp.float32))
+    lse = _pad_seq(lse, nq * bq, axis=2)[:, :, None]   # [B, H, 1, S]
+    d = _pad_seq(d, nq * bq, axis=2)[:, :, None]
+    qf = _pad_seq(q.reshape(B, S, H * hd), nq * bq)
+    dof = _pad_seq(do.reshape(B, S, H * hdv), nq * bq)
+    kf = _pad_seq(k.reshape(B, S, KV * hd), nk * bk)
+    vf = _pad_seq(v.reshape(B, S, KV * hdv), nk * bk)
+    kw = dict(scale=scale, causal=causal, window=window, bq=bq, bk=bk,
+              seq_len=S, heads=hb, group=group, hd=hd, hdv=hdv)
+    params = functools.partial(pltpu.CompilerParams,
+                               vmem_limit_bytes=BWD_VMEM_LIMIT)
+
+    # dK, dV: grid (B, KV blocks, nk, nsub, nq), the q-blocks innermost
+    def q_at(b, g, ik, j, iq):
+        # a skipped step maps to a q-block that sees ik: no new DMA
+        first, last = _q_range(ik, bq=bq, bk=bk, nq=nq, causal=causal,
+                               window=window, hi=jnp.minimum)
+        return b, jnp.minimum(jnp.maximum(iq, first), last), g * nsub + j
+
+    def q_rows(*idx):
+        b, iq, h = q_at(*idx)
+        return b, h, 0, iq
+
+    def kv_at(b, g, ik, j, iq):
+        return b, ik, g
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, nq=nq, nsub=nsub, **kw),
+        grid=(B, KV // kvb, nk, nsub, nq),
+        in_specs=[
+            pl.BlockSpec((1, bq, hb * hd), q_at),
+            pl.BlockSpec((1, bk, kvb * hd), kv_at),
+            pl.BlockSpec((1, bk, kvb * hdv), kv_at),
+            pl.BlockSpec((1, bq, hb * hdv), q_at),
+            pl.BlockSpec((1, hb, 1, bq), q_rows),
+            pl.BlockSpec((1, hb, 1, bq), q_rows),
+        ],
+        out_specs=[pl.BlockSpec((1, bk, kvb * hd), kv_at),
+                   pl.BlockSpec((1, bk, kvb * hdv), kv_at)],
+        out_shape=[jax.ShapeDtypeStruct((B, nk * bk, KV * hd), k.dtype),
+                   jax.ShapeDtypeStruct((B, nk * bk, KV * hdv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((kvb, bk, hd), jnp.float32),
+                        pltpu.VMEM((kvb, bk, hdv), jnp.float32)],
+        compiler_params=params(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, d)
+
+    # dQ: grid (B, head blocks, nq, nk), the k-blocks innermost
+    q_map, kv_map, rows_map = _q_major_maps(
+        bq=bq, bk=bk, nk=nk, causal=causal, window=window, hb=hb,
+        group=group, kvb=kvb)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, nk=nk, **kw),
+        grid=(B, H // hb, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, bq, hb * hd), q_map),
+            pl.BlockSpec((1, bk, kvb * hd), kv_map),
+            pl.BlockSpec((1, bk, kvb * hdv), kv_map),
+            pl.BlockSpec((1, bq, hb * hdv), q_map),
+            pl.BlockSpec((1, hb, 1, bq), rows_map),
+            pl.BlockSpec((1, hb, 1, bq), rows_map),
+        ],
+        out_specs=pl.BlockSpec((1, bq, hb * hd), q_map),
+        out_shape=jax.ShapeDtypeStruct((B, nq * bq, H * hd), q.dtype),
+        scratch_shapes=[pltpu.VMEM((hb, bq, hd), jnp.float32)],
+        compiler_params=params(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, d)
+    return (dq[:, :S].reshape(B, S, H, hd), dk[:, :S].reshape(B, S, KV, hd),
+            dv[:, :S].reshape(B, S, KV, hdv))
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref,

@@ -1,10 +1,16 @@
 """Jit'd public wrappers for the flash-attention kernels.
 
 ``attention`` / ``decode`` run the Pallas kernels directly.
-``attention_grad`` is the trainable entry the model layer routes through:
-its forward is the flash kernel and its VJP replays the pure-jnp oracle
-(Pallas kernels do not differentiate), so gradients match the reference
-math the models were validated against.
+``attention_grad`` is the trainable entry the model layer routes through.
+Its primal is the flash forward.  Under differentiation the forward also
+saves each row's log-sum-exp, and the VJP is the Pallas flash backward
+(``flash_attention_bwd``: a dK/dV kernel and a dQ kernel on the
+forward's tiling), which recomputes the probabilities block by block.
+
+The backward runs under a jit of its own named ``flash_attention_bwd``:
+its kernels' instructions in a step's HLO are then named after it, and
+only the forward's are named ``attention_grad``, the name the benchmark's
+forward rooflines look for.
 """
 from __future__ import annotations
 
@@ -12,6 +18,7 @@ import functools
 
 import jax
 
+from repro.kernels.flash_attention import flash_attention as _fa
 from repro.kernels.flash_attention.flash_attention import (flash_attention,
                                                            flash_decode)
 from repro.kernels.flash_attention.ref import attention_ref, decode_ref
@@ -36,6 +43,11 @@ def decode(q, ck, cv, pos, *, window: int = 0, block_k: int = 128,
                         interpret=interpret)
 
 
+flash_attention_bwd = jax.jit(
+    _fa.flash_attention_bwd,
+    static_argnames=("causal", "window", "scale", "interpret"))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _attention_grad(q, k, v, causal, window, scale, interpret):
     return flash_attention(q, k, v, causal=causal, window=window,
@@ -43,17 +55,14 @@ def _attention_grad(q, k, v, causal, window, scale, interpret):
 
 
 def _attention_grad_fwd(q, k, v, causal, window, scale, interpret):
-    return (_attention_grad(q, k, v, causal, window, scale, interpret),
-            (q, k, v))
+    o, lse = _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                     scale=scale, interpret=interpret)
+    return o, (q, k, v, o, lse)
 
 
 def _attention_grad_bwd(causal, window, scale, interpret, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda qq, kk, vv: attention_ref(qq, kk, vv, causal=causal,
-                                         window=window, scale=scale),
-        q, k, v)
-    return vjp(g)
+    return flash_attention_bwd(*res, g, causal=causal, window=window,
+                               scale=scale, interpret=interpret)
 
 
 _attention_grad.defvjp(_attention_grad_fwd, _attention_grad_bwd)
@@ -63,10 +72,12 @@ _attention_grad.defvjp(_attention_grad_fwd, _attention_grad_bwd)
                                              "interpret"))
 def attention_grad(q, k, v, *, causal: bool = True, window: int = 0,
                    scale: float | None = None, interpret: bool = True):
-    """Flash forward with a reference-math VJP (safe under value_and_grad);
-    v may have its own head dim, and ``scale`` defaults to 1/sqrt(hd)."""
+    """Flash forward with the flash backward as its VJP (safe under
+    value_and_grad); v may have its own head dim, and ``scale`` defaults
+    to 1/sqrt(hd)."""
     return _attention_grad(q, k, v, causal, window, scale, interpret)
 
 
 __all__ = ["attention", "attention_grad", "attention_ref", "decode",
-           "decode_ref", "flash_attention", "flash_decode"]
+           "decode_ref", "flash_attention", "flash_attention_bwd",
+           "flash_decode"]

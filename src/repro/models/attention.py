@@ -5,8 +5,9 @@ Pure-jnp reference math is the oracle; the Pallas kernels in
 seam.  ``attention_forward`` / ``attention_decode`` take a ``backend``
 argument (default: the model config's ``attn_backend`` field, ``"auto"``)
 resolved by ``repro.kernels.backend.resolve_backend`` — ``kernel`` runs the
-flash forward (with a reference-math VJP for training) and the streaming
-decode kernel; ``ref`` keeps the jnp expressions below bit-for-bit.
+flash forward (with the flash backward as its VJP for training) and the
+streaming decode kernel; ``ref`` keeps the jnp expressions below
+bit-for-bit.
 Cross-attention (``kv_x`` / ``cross_kv``) always uses the reference path:
 its keys come from a different sequence length and carry the decode
 sharding hints the kernel does not model.

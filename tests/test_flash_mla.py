@@ -1,6 +1,6 @@
-"""The flash-attention forward at latent attention's shapes (DeepSeek-V2):
-q/k heads of 192 (128 nope + 64 rope), v heads of 128, and an explicit
-softmax scale, in interpret mode against the jnp oracle."""
+"""The flash-attention forward and backward at latent attention's shapes
+(DeepSeek-V2): q/k heads of 192 (128 nope + 64 rope), v heads of 128, and
+an explicit softmax scale, in interpret mode against the jnp oracle."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -44,7 +44,7 @@ def test_attention_qk192_v128_scaled(S, block, dtype):
 
 
 def test_attention_grad_qk192_v128_scaled():
-    """Flash forward and reference-math VJP at the same shapes and scale:
+    """Flash forward and flash backward at the same shapes and scale:
     value and every input gradient match the oracle."""
     q, k, v = _qkv(1, 128, 2, 192, 128, jnp.float32)
 
@@ -59,3 +59,23 @@ def test_attention_grad_qk192_v128_scaled():
     for a, b in zip(gk, gr):
         assert a.shape == b.shape
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
+@pytest.mark.parametrize("S", [256, 200], ids=["planned", "padded"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_bwd_qk192_v128_scaled(S, dtype):
+    """The Pallas backward's dq, dk (192 wide) and dv (128 wide) against
+    jax.vjp of the fp32 oracle for a random cotangent, two heads a block
+    and two blocks of heads: float32 to rounding, bfloat16 to 2% of the
+    largest gradient."""
+    q, k, v = _qkv(2, S, 4, 192, 128, dtype)
+    g = jax.random.normal(jax.random.PRNGKey(8), (2, S, 4, 128), dtype)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+    kw = dict(causal=True, scale=SCALE)
+    gk = jax.vjp(lambda *a: FA.attention_grad(*a, **kw), q, k, v)[1](g)
+    gr = jax.vjp(lambda *a: FA.attention_ref(*a, **kw), *f32[:3])[1](f32[3])
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for a, b in zip(gk, gr):
+        assert a.shape == b.shape and a.dtype == dtype
+        err = jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+        assert float(err) < tol * float(jnp.max(jnp.abs(b)))

@@ -144,7 +144,7 @@ def test_flash_attention_noncausal():
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 16),
                                            (False, 0)])
 def test_flash_attention_grad_matches_ref(causal, window):
-    """The trainable entry: flash forward, reference-math VJP.  Both the
+    """The trainable entry: flash forward, flash backward.  Both the
     value and every input gradient must match the jnp oracle under
     value_and_grad."""
     ks = jax.random.split(KEY, 3)
@@ -165,6 +165,105 @@ def test_flash_attention_grad_matches_ref(causal, window):
     assert abs(float(vk - vr)) < 1e-2
     for a, b in zip(gk, gr):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
+def _vjps(q, k, v, g, **kw):
+    """The kernel's and the fp32 oracle's (dq, dk, dv) for cotangent g."""
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+    gk = jax.vjp(lambda *a: FA.attention_grad(*a, **kw), q, k, v)[1](g)
+    gr = jax.vjp(lambda *a: FA.attention_ref(*a, **kw), *f32[:3])[1](f32[3])
+    return gk, gr
+
+
+# (B, S, H, KV, hd, dtype, causal, window); every block from the plan
+@pytest.mark.parametrize("B,S,H,KV,hd,dtype,causal,window", [
+    # 128-row blocks, the padded tail of 600 in a fifth, pairs skipped
+    pytest.param(1, 600, 4, 2, 32, jnp.float32, True, 0, id="gqa-600-f32"),
+    pytest.param(1, 1024, 4, 4, 64, jnp.bfloat16, True, 0,
+                 id="1024-planned-bf16"),                  # 3 of 4 pairs
+    pytest.param(1, 200, 4, 4, 32, jnp.bfloat16, False, 0,
+                 id="noncausal-200-bf16"),                 # padded to 208
+    pytest.param(2, 200, 4, 2, 32, jnp.float32, False, 0,
+                 id="noncausal-200-f32"),
+    pytest.param(1, 512, 4, 2, 32, jnp.float32, True, 16,
+                 id="window16-512-f32"),      # shorter than a block
+    pytest.param(1, 1024, 4, 2, 32, jnp.bfloat16, True, 200,
+                 id="window200-1024-bf16"),   # spans several blocks
+    # MQA: a block holds 4 of the 8 heads sharing the one KV head, so
+    # dK/dV sum over two head blocks
+    pytest.param(1, 256, 8, 1, 64, jnp.float32, True, 0, id="mqa-256-f32"),
+    pytest.param(1, 200, 8, 1, 64, jnp.bfloat16, True, 0,
+                 id="mqa-200-bf16"),
+])
+def test_flash_attention_bwd_matches_ref(B, S, H, KV, hd, dtype, causal,
+                                         window):
+    """The Pallas backward's dq, dk and dv against jax.vjp of the fp32
+    oracle, for a random cotangent: float32 to rounding, bfloat16 (bf16
+    P and dS on the MXU) to 2% of the largest gradient."""
+    ks = jax.random.split(KEY, 4)
+    q, k, v, g = (jax.random.normal(kk, shape, dtype) for kk, shape in zip(
+        ks, [(B, S, H, hd), (B, S, KV, hd), (B, S, KV, hd), (B, S, H, hd)]))
+    gk, gr = _vjps(q, k, v, g, causal=causal, window=window)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for a, b in zip(gk, gr):
+        assert a.shape == b.shape and a.dtype == dtype
+        assert _max_err(a, b) < tol * float(jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("causal,window,S", [(True, 0, 600), (True, 16, 512),
+                                             (False, 0, 200)])
+def test_flash_attention_fwd_lse(causal, window, S):
+    """The training forward's log-sum-exp: the oracle's scaled, masked
+    scores' logsumexp, for every head and row."""
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_fwd)
+    q, k, v = _qkv(1, S, 4, 2, 32)
+    o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                 scale=0.3)
+    assert lse.shape == (1, 4, S) and lse.dtype == jnp.float32
+    s = 0.3 * jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2))
+    qi, kj = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    vis = (kj <= qi) & ((kj > qi - window) if window else True) \
+        if causal else jnp.ones((S, S), bool)
+    ref = jax.nn.logsumexp(jnp.where(vis, s, -jnp.inf), axis=-1)
+    assert _max_err(lse, ref) < 1e-4
+    assert _max_err(o, FA.attention_ref(q, k, v, causal=causal,
+                                        window=window, scale=0.3)) < 1e-4
+
+
+@pytest.mark.parametrize("S,H,KV,hd,hdv,block,heads,pairs", [
+    (2048, 32, 32, 64, 64, 512, 4, 10),       # the StableLM cell
+    (4096, 16, 16, 192, 128, 512, 2, 36),     # the DeepSeek cell
+    (256, 8, 1, 64, 64, 256, 4, 1),           # MQA: two head blocks a KV
+])
+def test_flash_attention_bwd_plan(S, H, KV, hd, hdv, block, heads, pairs):
+    """The backward's plan at the cells' shapes, and its pairs, counted by
+    k-block, against a brute-force count of block pairs holding a visible
+    (query, key) pair: the forward's pairs, enumerated the other way."""
+    from repro.kernels.flash_attention.flash_attention import plan
+    pn = plan(S, H, KV, hd, jnp.bfloat16, hdv=hdv, backward=True)
+    assert (pn.block_q, pn.block_k, pn.heads, pn.pairs) == \
+        (block, block, heads, pairs)
+
+    def brute(S, bq, bk, causal, window):
+        qi = np.arange(S)[:, None]
+        kj = np.arange(S)[None, :]
+        vis = np.ones((S, S), bool)
+        if causal:
+            vis = (kj <= qi) & ((kj > qi - window) if window else True)
+        return sum(vis[i:i + bq, j:j + bk].any()
+                   for i in range(0, S, bq) for j in range(0, S, bk))
+
+    for S_, b, causal, window in [(200, 64, True, 0), (600, None, True, 0),
+                                  (512, 128, True, 200), (300, 32, True, 16),
+                                  (256, 64, False, 0), (1000, 96, True, 333),
+                                  (1024, None, True, 16), (48, None, True, 0)]:
+        for bq, bk in ((b, b), (b, b and 2 * b), (b and 2 * b, b)):
+            kw = dict(causal=causal, window=window, block_q=bq, block_k=bk)
+            bw = plan(S_, H, KV, hd, jnp.float32, backward=True, **kw)
+            assert bw.pairs == brute(S_, bw.block_q, bw.block_k, causal,
+                                     window)
+            assert bw.pairs == plan(S_, H, KV, hd, jnp.float32, **kw).pairs
 
 
 @pytest.mark.parametrize("pos", [0, 5, 39])

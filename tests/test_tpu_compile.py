@@ -83,16 +83,49 @@ def test_flash_attention(one_chip):
                        q, q, q)
 
 
+def _kernel_names(text):
+    """The instruction names of a compiled program's Mosaic kernels."""
+    return [line.split("=", 1)[0].strip().removeprefix("ROOT ").lstrip("%")
+            for line in text.split("\n")
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _assert_fwd_bwd_kernels(q, k, v, **kw):
+    """value_and_grad of ``attention_grad`` inside a one-layer scan, as the
+    model's layer scan calls it: the forward's kernel is the one
+    instruction named ``attention_grad`` (what the benchmark's forward
+    rooflines read), and the backward's dK/dV and dQ kernels are named
+    ``flash_attention_bwd`` (what ``flash_bwd_roofline`` reads)."""
+    import re
+
+    def loss(q, k, v):
+        def layer(c, _):
+            out = attention_grad(q, k, v, interpret=False, **kw)
+            return c + jnp.sum(out.astype(jnp.float32)), None
+        return jax.lax.scan(layer, 0.0, None, length=1)[0]
+    names = _kernel_names(jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2))).lower(q, k, v).compile().as_text())
+    fwd = [n for n in names if re.match(r"attention_grad\b", n)]
+    bwd = [n for n in names if re.match(r"flash_attention_bwd\b", n)]
+    assert len(fwd) == 1 and len(bwd) == 2 and len(names) == 3, names
+
+
 def test_attention_grad_fwd_bwd(one_chip):
+    """StableLM's attention at the train cell's 3 rows of 2048: the
+    forward with its log-sum-exp and the Pallas backward fit VMEM, causal
+    and with a window that skips blocks."""
     q = _shape(one_chip, (B, S, H, HD), jnp.bfloat16)
+    assert plan(S, H, H, HD, jnp.bfloat16, backward=True).block_q == 512
     for window in (0, WINDOW):
-        def loss(q, k, v, window=window):
-            out = attention_grad(q, k, v, causal=True, window=window,
-                                 interpret=False)
-            return jnp.sum(out.astype(jnp.float32))
-        # value_and_grad: the forward value keeps the kernel live (the
-        # VJP replays the reference math)
-        _assert_kernel(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+        _assert_fwd_bwd_kernels(q, q, q, causal=True, window=window)
+
+
+def test_attention_grad_fwd_bwd_mla(one_chip):
+    """The same at DeepSeek-V2's latent attention, 2 rows of 4096: q/k
+    heads of 192, v heads of 128, explicit scale."""
+    q = _shape(one_chip, (2, 4096, 16, 192), jnp.bfloat16)
+    v = _shape(one_chip, (2, 4096, 16, 128), jnp.bfloat16)
+    _assert_fwd_bwd_kernels(q, q, v, causal=True, scale=0.1352)
 
 
 def test_flash_attention_mla(one_chip):
@@ -108,8 +141,8 @@ def test_flash_attention_mla(one_chip):
 def test_deepseek_train_step(topo, one_chip, monkeypatch):
     """The DeepSeek-V2 cell's whole training step (1 dense + 4 MoE layers
     holding 8 of 64 experts, vocabulary 12800, 2 rows of 4096 tokens, bf16
-    compute) fits one chip, with the flash forward and the held experts'
-    grouped matmuls as Mosaic kernels."""
+    compute) fits one chip, with the flash forward and backward and the
+    held experts' grouped matmuls as Mosaic kernels."""
     import dataclasses
 
     import repro.models.mla as mla
@@ -147,6 +180,7 @@ def test_deepseek_train_step(topo, one_chip, monkeypatch):
           f"{mem.peak_memory_in_bytes / 1e9:.2f} GB")
     text = compiled.as_text()
     assert "jit(attention_grad)/pallas_call" in text
+    assert "jit(flash_attention_bwd)/pallas_call" in text
     assert "/moe/experts/jit(gmm)/pallas_call" in text
     assert "jit(tgmm)/pallas_call" in text
 

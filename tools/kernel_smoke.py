@@ -13,7 +13,7 @@ device engine for 2 BSP steps under ``wire="measured"`` with
     never change what goes on the wire.
 
 The flash cell checks the training forward (kernel vs jnp oracle), its
-reference-math VJP, and the streaming decode kernel against the grouped
+flash-backward VJP, and the streaming decode kernel against the grouped
 jnp decode, full-cache and ring-window.
 
   PYTHONPATH=src python tools/kernel_smoke.py
