@@ -8,7 +8,7 @@ PYTEST := env PYTHONPATH=src timeout
 SMOKE_TIMEOUT ?= 300
 TIER1_TIMEOUT ?= 900
 
-.PHONY: smoke tier1 bench strategies elastic hybrid comm kernels serve obs
+.PHONY: smoke tier1 strategies elastic hybrid comm kernels serve obs
 
 # Fast subset: pure-host unit tests (collectives shim units, compression,
 # schedulers, configs, models). ~1 min.
@@ -73,6 +73,3 @@ obs:
 # compositions.
 tier1: strategies elastic hybrid comm kernels serve obs
 	$(PYTEST) $(TIER1_TIMEOUT) python -m pytest -q
-
-bench:
-	env PYTHONPATH=src python -m benchmarks.run

@@ -1,7 +1,7 @@
 """The survey's contribution — its taxonomy of distributed-DL techniques —
 as first-class composable features:
 
-  parallelism.py      §3.2   data/tensor/hybrid sharding rules
+  parallelism.py      §3.2   data/tensor sharding rules by parameter role
   pipeline.py         §3.2.3 GPipe micro-batch pipeline
   parameter_server.py §3.3.1 centralized architecture (TPU adaptation)
   allreduce.py        §3.3.1 decentralized topologies (ring/tree/butterfly)

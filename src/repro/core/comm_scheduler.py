@@ -11,9 +11,6 @@ models that placement: given per-layer backward compute times and gradient
 sizes, it computes iteration time under (a) no overlap (all comm at the
 end), (b) random bucket order, (c) reverse-layer priority order (TicTac),
 and the classic bucketing trade-off (latency alpha vs bandwidth beta).
-The projected timings feed benchmarks/comm_schedule_bench.py; the dominant
-`collective` roofline term of the dry-run is the same quantity measured
-from compiled HLO.
 
 These are the *primitives*.  The executable surface engines consume is
 ``repro.comm.plan.CommPlan``, which owns the bucket fusion + issue order

@@ -14,17 +14,16 @@ Hybrid data+model parallelism in the Mesh-TensorFlow style the survey covers
 Sharding the second dim over "data" is the ZeRO/FSDP choice: XLA inserts a
 per-layer all-gather inside the scan, trading collective time for the n-fold
 parameter-memory reduction that makes the 1T-param config representable.
-The hillclimb in EXPERIMENTS.md §Perf measures exactly this trade.
 
 Stacked (scanned) layers get leading None axes automatically.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 from jax.sharding import PartitionSpec as P
-from jax.tree_util import DictKey, FlattenedIndexKey, GetAttrKey, SequenceKey
+from jax.tree_util import DictKey, GetAttrKey, SequenceKey
 
 COL = ("data", "model")
 ROW = ("model", "data")
@@ -87,24 +86,14 @@ def _trailing_spec(names: list[str], ndim: int) -> Tuple[Optional[str], ...]:
     return (None,) * min(ndim, 2)
 
 
-def param_specs(params, multi_pod: bool = False, policy: str = "fsdp"):
-    """PartitionSpec pytree matching `params` (works on ShapeDtypeStructs).
-
-    policy:
-      fsdp    : weights sharded over BOTH data (ZeRO-3) and model (TP) —
-                minimal memory, per-layer all-gathers (the default).
-      tp_only : weights sharded over model only, replicated over data —
-                no weight gathers; right for serving and for models whose
-                params fit replicated (hillclimb lever, EXPERIMENTS §Perf).
-    """
-    assert policy in ("fsdp", "tp_only"), policy
+def param_specs(params):
+    """PartitionSpec pytree matching `params` (works on ShapeDtypeStructs):
+    weights sharded over BOTH data (ZeRO-3 / FSDP) and model (TP)."""
 
     def one(path, leaf):
         names = _path_names(path)
         ndim = len(leaf.shape)
         trailing = _trailing_spec(names, ndim)
-        if policy == "tp_only":
-            trailing = tuple(None if ax == "data" else ax for ax in trailing)
         lead = (None,) * (ndim - len(trailing))
         return P(*(lead + tuple(trailing)))
 
@@ -130,94 +119,7 @@ def model_axis_dim(path, ndim: int):
     return None
 
 
-# ------------------------------------------------------- attention hints
-# Decode-attention guidance: with few KV heads (GQA), GSPMD's default is to
-# all-gather each layer's hd-sharded KV cache (GBs/token).  Constraining
-# the scores to be model-replicated and the attention output to stay
-# hd-sharded flips the program to partial-score + all-reduce (MBs/token).
-_ATTN_HINTS: dict = {"enabled": False, "data": ("data",), "mode": "hd"}
-
-
-def set_attn_decode_hints(enabled: bool, multi_pod: bool = False,
-                          mode: str = "hd"):
-    """mode 'hd': cache sharded on head_dim; partial scores + all-reduce.
-    mode 'seq': cache sharded on sequence (flash-decoding); local scores
-    and softmax-combine / output partial-sums are the only collectives."""
-    _ATTN_HINTS["enabled"] = enabled
-    _ATTN_HINTS["data"] = data_axes(multi_pod)
-    _ATTN_HINTS["mode"] = mode
-
-
-def attn_decode_constraint(x, kind: str, shard_batch: bool = True):
-    if not _ATTN_HINTS["enabled"]:
-        return x
-    from jax.lax import with_sharding_constraint as wsc
-    b = _ATTN_HINTS["data"] if shard_batch else None
-    seq = _ATTN_HINTS["mode"] == "seq"
-    try:
-        if kind == "scores":        # [B, H, q, L] — replicated over model
-            return wsc(x, P(b, None, None, None))
-        if kind == "out":           # [B, q, H, hd] — keep hd on model
-            return wsc(x, P(b, None, None, "model"))
-        if kind == "scores5d":      # [B, KV, G, q, L]
-            return wsc(x, P(b, None, None, None, "model") if seq
-                       else P(b, None, None, None, None))
-        if kind == "out5d":         # [B, q, KV, G, hd]
-            return wsc(x, P(b, None, None, None, None) if seq
-                       else P(b, None, None, None, "model"))
-        if kind == "q5d":           # [B, q, KV, G, hd] — reshard q (tiny!)
-            # hd mode: q to hd-on-model so the score contraction is local
-            # to each cache shard (partial scores + AR, never a cache AG).
-            # seq mode: q replicated over model.
-            return wsc(x, P(b, None, None, None, None) if seq
-                       else P(b, None, None, None, "model"))
-        if kind == "cache4d":       # [B, L, KV, hd] — pin storage layout
-            return wsc(x, P(b, "model", None, None) if seq
-                       else P(b, None, None, "model"))
-    except Exception:
-        return x
-    return x
-
-
-# ---------------------------------------------------------------- MoE hints
-# When set (see set_moe_sharding_hints), repro.models.moe applies explicit
-# with_sharding_constraint on the dispatch buffers so GSPMD lowers the
-# token shuffle to all-to-all instead of gather-via-all-gather — the
-# expert-parallel pattern the survey's hybrid-parallelism section is about.
-_MOE_HINTS: dict = {"enabled": False, "data": ("data",), "model": "model",
-                    "mode": "full"}
-
-
-def set_moe_sharding_hints(enabled: bool, multi_pod: bool = False,
-                           mode: str = "full"):
-    """mode 'full': constrain tokens + expert buffers.
-    mode 'expert_only': constrain only the expert-sharded buffer."""
-    _MOE_HINTS["enabled"] = enabled
-    _MOE_HINTS["data"] = data_axes(multi_pod)
-    _MOE_HINTS["mode"] = mode
-
-
-def moe_constraint(x, kind: str):
-    """kind: 'tokens' [T, d] or 'experts' [E, C, d]."""
-    if not _MOE_HINTS["enabled"]:
-        return x
-    from jax.lax import with_sharding_constraint as wsc
-    try:
-        if kind == "tokens" and _MOE_HINTS["mode"] == "full":
-            return wsc(x, P(_MOE_HINTS["data"], None))
-        if kind == "experts":
-            return wsc(x, P(_MOE_HINTS["model"], None, None))
-    except Exception:   # no mesh in context: constraint is a no-op request
-        return x
-    return x
-
-
 def data_axes(multi_pod: bool = False):
     """Mesh axes that shard the batch dimension."""
     return ("pod", "data") if multi_pod else ("data",)
 
-
-def batch_spec(ndim: int, multi_pod: bool = False, shard_batch: bool = True):
-    """Spec for an input whose dim 0 is batch."""
-    b = data_axes(multi_pod) if shard_batch else None
-    return P(b, *([None] * (ndim - 1)))

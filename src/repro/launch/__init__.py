@@ -1,2 +1,3 @@
-"""Launchers: production meshes, the multi-pod dry-run, roofline analysis,
-and host-scale train/serve entry points."""
+"""Launchers: host-scale train/serve entry points, XLA host-device setup
+(``env``), the hybrid training mesh, and the sharding-spec and roofline
+helpers."""

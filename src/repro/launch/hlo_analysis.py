@@ -1,4 +1,4 @@
-"""Extract roofline terms from a lowered/compiled dry-run artifact.
+"""Extract roofline terms from a lowered/compiled program.
 
 ``cost_analysis()`` provides HLO FLOPs and bytes; collective bytes are NOT
 in cost_analysis, so we parse the optimized HLO text and convert each

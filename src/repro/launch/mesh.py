@@ -1,16 +1,6 @@
-"""Production meshes.  Functions (never module-level constants) so importing
-this module never touches jax device state."""
+"""The hybrid training mesh.  A function (never a module-level constant)
+so importing this module never touches jax device state."""
 from __future__ import annotations
-
-import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: 16x16 = 256 chips (data, model).
-    Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_hybrid_mesh(devices, data: int, tensor: int, stage: int,
@@ -27,13 +17,6 @@ def make_hybrid_mesh(devices, data: int, tensor: int, stage: int,
                          f"have {len(devices)}")
     from jax.sharding import Mesh
     return Mesh(np.array(devices[:n]).reshape(data, tensor, stage), axes)
-
-
-def make_host_mesh(model_axis: int = 1):
-    """Whatever this host actually has (smoke tests / examples)."""
-    n = len(jax.devices())
-    data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline analysis (per chip)

@@ -1,4 +1,4 @@
-"""Roofline analysis from dry-run artifacts (deliverable g).
+"""Roofline terms of a compiled program's cost record.
 
 Per (arch x shape x mesh) record:
   compute term    = HLO_FLOPs / peak_FLOP/s            (per-chip module)
@@ -7,17 +7,10 @@ Per (arch x shape x mesh) record:
 
 plus MODEL_FLOPS = 6*N*D (training; 2*N_active*D_dec for decode) and the
 useful-compute ratio MODEL_FLOPS / (HLO_FLOPs * chips).
-
-Usage: PYTHONPATH=src python -m repro.launch.roofline [--dir results/dryrun]
-Writes results/roofline.json and prints the table.
 """
 from __future__ import annotations
 
-import argparse
-import glob
-import json
-import os
-from typing import Dict, List
+from typing import Dict
 
 from repro.configs import ARCHS, INPUT_SHAPES
 from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
@@ -59,93 +52,3 @@ def analyze_record(rec: Dict, chips: int) -> Dict:
         "useful_ratio": round(ratio, 4),
         "bound_step_s": round(max(terms.values()), 6),
     }
-
-
-def _rank(rec: Dict, path: str) -> int:
-    """Cost-source quality: probe (per-layer exact, extrapolated) >
-    unrolled > scanned (XLA counts scan bodies once)."""
-    if "__tp_only" in path or "__moehints" in path:
-        return -1      # hillclimb variants never replace the baseline
-    if rec.get("probe"):
-        return 3
-    if rec.get("unrolled") or path.endswith("__unrolled.json"):
-        return 2
-    return 1
-
-
-def load_all(dir_: str) -> List[Dict]:
-    """One record per (arch, shape, mesh): the scanned compile is the
-    fits/compiles evidence; cost/collectives come from the best available
-    measurement (probe > unrolled > scanned)."""
-    base: Dict = {}       # scanned records (memory evidence)
-    best: Dict = {}       # best cost source
-    for p in sorted(glob.glob(os.path.join(dir_, "*.json"))):
-        with open(p) as f:
-            rec = json.load(f)
-        key = (rec.get("arch"), rec.get("shape"), rec.get("mesh"))
-        r = _rank(rec, p)
-        if r == 1:
-            base[key] = rec
-        if r > 0 and rec.get("status") in ("ok", "skipped"):
-            if key not in best or r > best[key][0]:
-                best[key] = (r, rec)
-    out = []
-    for key in sorted(set(base) | set(best),
-                      key=lambda t: (str(t[0]), str(t[1]), str(t[2]))):
-        rec = dict(base.get(key) or best[key][1])
-        if key in best and best[key][0] > 1 and rec.get("status") == "ok":
-            src = best[key][1]
-            rec["cost"] = src.get("cost", rec.get("cost"))
-            rec["collectives"] = src.get("collectives",
-                                         rec.get("collectives"))
-            rec["cost_source"] = "probe" if src.get("probe") else "unrolled"
-        out.append(rec)
-    return out
-
-
-def main():
-    ap = argparse.ArgumentParser()
-    default_dir = os.path.abspath(os.path.join(
-        os.path.dirname(__file__), "..", "..", "..", "results", "dryrun"))
-    ap.add_argument("--dir", default=default_dir)
-    ap.add_argument("--out", default=None)
-    args = ap.parse_args()
-    out_path = args.out or os.path.join(os.path.dirname(args.dir),
-                                        "roofline.json")
-
-    rows = []
-    for rec in load_all(args.dir):
-        if rec.get("status") != "ok":
-            rows.append({"arch": rec["arch"], "shape": rec["shape"],
-                         "mesh": rec.get("mesh"),
-                         "status": rec.get("status"),
-                         "reason": rec.get("reason", rec.get("error", ""))})
-            continue
-        chips = 512 if rec["mesh"] == "2x16x16" else 256
-        row = {"arch": rec["arch"], "shape": rec["shape"],
-               "mesh": rec["mesh"], "status": "ok", "chips": chips}
-        row.update(analyze_record(rec, chips))
-        rows.append(row)
-
-    with open(out_path, "w") as f:
-        json.dump(rows, f, indent=1)
-
-    hdr = (f"{'arch':24s} {'shape':12s} {'mesh':8s} {'compute':>10s} "
-           f"{'memory':>10s} {'collect':>10s} {'dominant':>10s} "
-           f"{'useful':>7s}")
-    print(hdr)
-    print("-" * len(hdr))
-    for r in rows:
-        if r.get("status") != "ok":
-            print(f"{r['arch']:24s} {r['shape']:12s} {str(r.get('mesh')):8s} "
-                  f"{r.get('status'):>10s}  {r.get('reason','')[:40]}")
-            continue
-        print(f"{r['arch']:24s} {r['shape']:12s} {r['mesh']:8s} "
-              f"{r['compute_s']:10.4f} {r['memory_s']:10.4f} "
-              f"{r['collective_s']:10.4f} {r['dominant']:>10s} "
-              f"{r['useful_ratio']:7.3f}")
-    print(f"\nwrote {out_path}")
-
-
-if __name__ == "__main__":
-    main()

@@ -1,18 +1,18 @@
 """ShapeDtypeStruct stand-ins + sharding specs for every (arch x shape).
 
-`input_specs(cfg, shape)` produces the exact abstract inputs the dry-run
-lowers against (no allocation); `cache_specs` / `batch_sharding` assign
-PartitionSpecs with divisibility-aware fallbacks (e.g. long_500k batch=1:
-the batch axis cannot shard, so the sequence axis of attention caches
-shards over `data` instead, and SSM states shard heads over `model`).
+`train_input_specs(cfg, shape)` produces abstract inputs (no allocation);
+`cache_specs` assigns PartitionSpecs with divisibility-aware fallbacks
+(e.g. long_500k batch=1: the batch axis cannot shard, so the sequence
+axis of attention caches shards over `data` instead, and SSM states shard
+heads over `model`).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 from jax.tree_util import DictKey, SequenceKey
 
 from repro.configs.base import InputShape, ModelConfig
@@ -29,13 +29,6 @@ def _div(n: int, k: int) -> bool:
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
-
-
-# ------------------------------------------------------------------ batches
-def batch_shardable(shape: InputShape, mesh: Mesh) -> bool:
-    sizes = mesh_axis_sizes(mesh)
-    dp = sizes.get("data", 1) * sizes.get("pod", 1)
-    return _div(shape.global_batch, dp)
 
 
 def train_input_specs(cfg: ModelConfig, shape: InputShape) -> Dict[str, Any]:
@@ -57,16 +50,6 @@ def train_input_specs(cfg: ModelConfig, shape: InputShape) -> Dict[str, Any]:
     return specs
 
 
-def batch_specs_tree(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
-                     multi_pod: bool) -> Dict[str, P]:
-    shard_b = batch_shardable(shape, mesh)
-    b = data_axes(multi_pod) if shard_b else None
-    out: Dict[str, P] = {}
-    for name, sds in train_input_specs(cfg, shape).items():
-        out[name] = P(b, *([None] * (len(sds.shape) - 1)))
-    return out
-
-
 # ------------------------------------------------------------------- caches
 def decode_window(cfg: ModelConfig, shape: InputShape) -> int:
     """window_override for the serve step (0 = full cache)."""
@@ -80,8 +63,7 @@ def decode_window(cfg: ModelConfig, shape: InputShape) -> int:
 
 
 def cache_leaf_spec(names, shape_t: Tuple[int, ...], *, multi_pod: bool,
-                    shard_batch: bool, model_n: int, data_n: int,
-                    policy: str = "auto") -> P:
+                    shard_batch: bool, model_n: int, data_n: int) -> P:
     name = names[-1] if names else ""
     dp = data_axes(multi_pod)
     b = dp if shard_batch else None
@@ -96,17 +78,6 @@ def cache_leaf_spec(names, shape_t: Tuple[int, ...], *, multi_pod: bool,
     if name in ("k", "v"):           # [..., B, L, KV, hd]
         nd = len(shape_t)
         lead = (None,) * (nd - 4)
-        if policy == "attn_hints_seq":
-            # flash-decoding storage: sequence over model, batch over data
-            l_spec = "model" if _div(shape_t[nd - 3], model_n) else None
-            return P(*(lead + (b, l_spec, None, None)))
-        if policy == "seq_data":
-            # flash-decoding layout: batch over model, sequence over data —
-            # the cache is fully partitioned without touching the (too few)
-            # KV heads, and only tiny per-token activations reshard.
-            b_spec = "model" if _div(shape_t[nd - 4], model_n) else None
-            l_spec = dp if _div(shape_t[nd - 3], data_n) else None
-            return P(*(lead + (b_spec, l_spec, None, None)))
         l_spec = None if shard_batch else (dp if _div(shape_t[nd - 3],
                                                       data_n) else None)
         mi = model_split(nd - 2, nd - 1)
@@ -140,7 +111,7 @@ def cache_leaf_spec(names, shape_t: Tuple[int, ...], *, multi_pod: bool,
 
 
 def cache_specs(cache_shapes, mesh: Mesh, multi_pod: bool,
-                shard_batch: bool, policy: str = "auto"):
+                shard_batch: bool):
     sizes = mesh_axis_sizes(mesh)
     model_n = sizes.get("model", 1)
     data_n = sizes.get("data", 1) * sizes.get("pod", 1)
@@ -154,50 +125,6 @@ def cache_specs(cache_shapes, mesh: Mesh, multi_pod: bool,
                 names.append(f"[{k.idx}]")
         return cache_leaf_spec(names, leaf.shape, multi_pod=multi_pod,
                                shard_batch=shard_batch, model_n=model_n,
-                               data_n=data_n, policy=policy)
+                               data_n=data_n)
 
     return jax.tree_util.tree_map_with_path(one, cache_shapes)
-
-
-# --------------------------------------------------------------- optimizers
-def opt_state_specs(opt_state_shapes, pspecs):
-    """Optimizer-state specs derived from the param specs (PS-style: the
-    optimizer shard lives with the parameter shard).  Handles same-shape
-    moments (sgd/adam m, v) and adafactor's factored vr/vc."""
-    import jax.tree_util as jtu
-
-    def match(path, leaf):
-        names = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
-        ndim = len(leaf.shape)
-        spec = _lookup_param_spec(pspecs, names)
-        if spec is None:
-            return P(*([None] * ndim))
-        st = tuple(spec)
-        if "vr" in names and len(st) >= 2:        # param shape minus last dim
-            return P(*st[:-1])
-        if "vc" in names and len(st) >= 2:        # minus second-to-last dim
-            return P(*(st[:-2] + st[-1:]))
-        if len(st) == ndim:
-            return P(*st)
-        return P(*([None] * ndim))
-
-    return jtu.tree_map_with_path(match, opt_state_shapes)
-
-
-def _lookup_param_spec(pspecs, names):
-    """Walk pspecs following the param-path segment of an optimizer path
-    (skipping the optimizer's own wrapper keys like m/v/f/vr/vc)."""
-    skip = {"m", "v", "f", "vr", "vc", "t"}
-    node = pspecs
-    for n in names:
-        if n in skip:
-            continue
-        if isinstance(node, dict) and n in node:
-            node = node[n]
-        elif isinstance(node, (list, tuple)) and n.startswith("["):
-            node = node[int(n[1:-1])]
-        elif isinstance(node, P):
-            break
-        else:
-            return None
-    return node if isinstance(node, P) else None

@@ -1,7 +1,7 @@
 """Model zoo: unified decoder LM + whisper encoder-decoder.
 
 `build_model(cfg)` returns a uniform functional API used by the trainer,
-server, dry-run, and benchmarks.
+the server, and the chip benchmark.
 """
 from __future__ import annotations
 

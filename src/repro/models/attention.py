@@ -9,8 +9,7 @@ flash forward (with the flash backward as its VJP for training) and the
 streaming decode kernel; ``ref`` keeps the jnp expressions below
 bit-for-bit.
 Cross-attention (``kv_x`` / ``cross_kv``) always uses the reference path:
-its keys come from a different sequence length and carry the decode
-sharding hints the kernel does not model.
+its keys come from a different sequence length.
 
 Cache layouts
 -------------
@@ -50,20 +49,14 @@ def _repeat_kv(k, n_rep):
     return jnp.repeat(k, n_rep, axis=2)
 
 
-def _sdpa(q, k, v, mask, decode_hints: bool = False):
+def _sdpa(q, k, v, mask):
     """q [B,Sq,H,hd] k/v [B,Sk,H,hd] mask [B,1,Sq,Sk] or broadcastable."""
-    from repro.core.parallelism import attn_decode_constraint
     hd = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    if decode_hints:
-        scores = attn_decode_constraint(scores, "scores")
     scores = scores / jnp.sqrt(jnp.float32(hd))
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
-    if decode_hints:
-        out = attn_decode_constraint(out, "out")
-    return out
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
 def _causal_mask(sq, sk, offset=0):
@@ -150,7 +143,7 @@ def attention_decode(p, x, pos, cache, cfg, *, window=0, cross_kv=None,
         kr = _repeat_kv(cross_kv["k"], H // KV)
         vr = _repeat_kv(cross_kv["v"], H // KV)
         mask = jnp.ones((1, 1, 1, kr.shape[1]), dtype=bool)
-        out = _sdpa(q, kr, vr, mask, decode_hints=True)
+        out = _sdpa(q, kr, vr, mask)
         out = dense(p["wo"], out.reshape(B, 1, H * hd))
         return out, cache
 
@@ -167,17 +160,12 @@ def attention_decode(p, x, pos, cache, cfg, *, window=0, cross_kv=None,
             q = apply_rope(q, posb, cfg.rope_theta)
             k = apply_rope(k, posb, cfg.rope_theta)
 
-    from repro.core.parallelism import attn_decode_constraint
     L = cache["k"].shape[1]
     slot = (pos % window) if window else pos
-    k = attn_decode_constraint(k, "cache4d")
-    v = attn_decode_constraint(v, "cache4d")
     ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
                                       (0, slot, 0, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
                                       (0, slot, 0, 0))
-    ck = attn_decode_constraint(ck, "cache4d")
-    cv = attn_decode_constraint(cv, "cache4d")
     if resolve_backend(backend) == "kernel":
         out = FA.decode(q, ck, cv, jnp.asarray(pos, jnp.int32),
                         window=window, interpret=kernel_interpret())
@@ -200,20 +188,15 @@ def _gqa_decode_sdpa(q, ck, cv, mask1d):
 
     q [B,1,H,hd]; ck/cv [B,L,KV,hd]; mask1d [L].  The repeat-free grouped
     einsum keeps the cache in its stored layout — on TPU this avoids an
-    H/KV-fold HBM blow-up, and under GSPMD it stops the partitioner from
-    replicating the repeated cache (EXPERIMENTS.md §Perf iter 4)."""
-    from repro.core.parallelism import attn_decode_constraint
+    H/KV-fold HBM blow-up."""
     B, _, H, hd = q.shape
     KV = ck.shape[2]
     G = H // KV
     qg = q.reshape(B, 1, KV, G, hd)
-    qg = attn_decode_constraint(qg, "q5d")
     scores = jnp.einsum("bqkgd,blkd->bkgql", qg.astype(jnp.float32),
                         ck.astype(jnp.float32))       # [B,KV,G,1,L]
-    scores = attn_decode_constraint(scores, "scores5d")
     scores = scores / jnp.sqrt(jnp.float32(hd))
     scores = jnp.where(mask1d[None, None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgql,blkd->bqkgd", probs.astype(cv.dtype), cv)
-    out = attn_decode_constraint(out, "out5d")
     return out.reshape(B, 1, H, hd)

@@ -1,8 +1,8 @@
 """Shared functional building blocks (pure-jnp, eval_shape friendly).
 
 All modules are (init, apply) pairs over plain dict pytrees so that
-``jax.eval_shape`` can abstract-init trillion-parameter configs for the
-multi-pod dry-run without allocating.
+``jax.eval_shape`` can abstract-init trillion-parameter configs without
+allocating.
 """
 from __future__ import annotations
 

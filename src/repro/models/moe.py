@@ -27,11 +27,9 @@ rows each expert got.  The absent experts' part, and the all-to-all that
 would bring it, are left out; the shared experts are computed alike on
 every member of the group.
 
-Capacity dispatch stays for every configuration without held experts:
-its [E, C, d] buffer has the static expert axis that GSPMD shards over
-the ``model`` mesh axis (``core.parallelism.moe_constraint``, used by
-``launch/dryrun.py --moe-hints``).  The dropless rows are grouped by
-counts known only at run time and have no such axis, and a held block
+Capacity dispatch serves every configuration without ``experts_held``:
+serving's per-slot decode and the Kimi-K2 and DeepSeek configurations of
+the zoo.  ``tests/test_moe.py`` pins its capacity rule.  A held block
 gets its tokens only from an all-to-all that does not exist yet.
 """
 from __future__ import annotations
@@ -130,8 +128,6 @@ def _capacity_experts(p, xt, gate, expert_ids, cfg):
         sorted_e = flat_e[sort_idx]
         counts = jnp.bincount(flat_e, length=E)                   # [E]
         starts = jnp.cumsum(counts) - counts                      # [E]
-        from repro.core.parallelism import moe_constraint
-        xt = moe_constraint(xt, "tokens")
 
         slot_c = jnp.arange(E * C) % C                            # [E*C]
         slot_e = jnp.arange(E * C) // C
@@ -140,7 +136,7 @@ def _capacity_experts(p, xt, gate, expert_ids, cfg):
         slot_token = sort_idx[slot_sorted_idx] // K               # source token
         buf = jnp.where(slot_valid[:, None],
                         xt[slot_token], jnp.zeros((), dtype=xt.dtype))
-        buf = moe_constraint(buf.reshape(E, C, d), "experts")
+        buf = buf.reshape(E, C, d)
 
     # ---- batched expert FFN (swiglu)
     with jax.named_scope("experts"):

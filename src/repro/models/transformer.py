@@ -2,11 +2,11 @@
 
 Layer stacks are grouped into homogeneous segments and executed with
 ``jax.lax.scan`` so that compile time and HLO size stay bounded for the
-61-layer / trillion-parameter dry-run configs.  Heterogeneous block patterns
+61-layer / trillion-parameter configs.  Heterogeneous block patterns
 (recurrentgemma's rglru-rglru-local) scan over *groups* of the pattern.
 
-Everything is eval_shape friendly: the multi-pod dry-run abstract-inits the
-params with ``jax.eval_shape`` and lowers against ShapeDtypeStructs only.
+Everything is eval_shape friendly: params can be abstract-initialised
+with ``jax.eval_shape`` and lowered against ShapeDtypeStructs only.
 """
 from __future__ import annotations
 
@@ -291,7 +291,7 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
                 body = jax.checkpoint(body)   # per-layer-group activation remat
             if unroll:
                 # analysis-only path: XLA cost_analysis counts while-loop
-                # bodies once, so the roofline dry-run unrolls the stack
+                # bodies once, so a roofline count unrolls the stack
                 seg_states_l = []
                 carry = (x, moe_total)
                 for gi in range(n_groups):

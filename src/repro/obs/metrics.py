@@ -186,7 +186,7 @@ class MetricsRegistry:
 
     def to_jsonl(self, **common) -> List[str]:
         """One JSON object per metric (``{"metric": name, "kind": ...,
-        **snapshot, **common}``) — the ``BENCH_*.json`` row convention."""
+        **snapshot, **common}``)."""
         lines = []
         for name in self.names():
             m = self._metrics[name]

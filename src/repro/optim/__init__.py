@@ -1,4 +1,4 @@
-"""Optimizers (pure pytree transforms; eval_shape friendly for the dry-run).
+"""Optimizers (pure pytree transforms; eval_shape friendly).
 
 Interface: ``opt.init(params) -> state``; ``opt.step(params, grads, state,
 lr) -> (params, state)``.

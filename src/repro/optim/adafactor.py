@@ -1,5 +1,5 @@
 """Adafactor (factored second moments) — the memory-frugal optimizer that
-makes trillion-parameter optimizer state representable on the dry-run mesh
+makes trillion-parameter optimizer state representable
 (state is O(rows + cols) per matrix instead of O(rows * cols))."""
 from __future__ import annotations
 

@@ -132,8 +132,7 @@ def state_bytes_per_device(plan: MeshPlan, zero: int, optimizer: str,
                            moment_dtype: str = "float32") -> Dict[str, int]:
     """Analytic persistent param+optimizer bytes per device for the mesh
     — the memory math of docs/hybrid.md (fp32 params; moments at
-    ``moment_dtype`` width, 2 B when quantized to bf16).  ``hybrid_bench``
-    cross-checks this against the engine's measured state sizes."""
+    ``moment_dtype`` width, 2 B when quantized to bf16)."""
     n_local = plan.n_local_params
     shard = sum(plan.shard_sizes)        # padded 1/D of the local block
     params = shard if zero == 3 else n_local

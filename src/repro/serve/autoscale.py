@@ -66,8 +66,8 @@ class RateEstimator:
 
 @dataclasses.dataclass(frozen=True)
 class AutoscalePolicy:
-    """``replica_rate``: req/s one replica sustains (measured, e.g. from a
-    serve_bench row).  ``scale_down_patience``: consecutive intervals the
+    """``replica_rate``: req/s one replica sustains (measured).
+    ``scale_down_patience``: consecutive intervals the
     desired count must stay below current before shrinking (hysteresis —
     scaling down evicts batch slots, so it should lag the signal)."""
     replica_rate: float = 1.0

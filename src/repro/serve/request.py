@@ -107,7 +107,7 @@ class Request:
 
 def summarize(requests: Sequence[Request], makespan: float) -> dict:
     """Aggregate serving metrics over completed requests: throughput plus
-    p50/p99 first-token and per-token latencies (the serve_bench row)."""
+    p50/p99 first-token and per-token latencies."""
     done = [r for r in requests if r.done]
     total_tokens = sum(len(r.output) for r in done)
     ttft = [r.first_token_latency() for r in done]

@@ -702,7 +702,7 @@ class DeviceEngine(ElasticWorkerSet):
 
     def per_device_state_bytes(self, st) -> Dict[str, int]:
         """Measured persistent bytes per device — comparable with the
-        hybrid engine's accounting (benchmarks/hybrid_bench.py).  Plain
+        hybrid engine's ``per_device_state_bytes``.  Plain
         SGD carries no optimizer state; params are replicated, EF
         residuals are per-worker."""
         K = self.cfg.num_workers

@@ -233,6 +233,47 @@ print("SMA-DEVICE-OK")
 """
 
 
+# ------------------- measured wire is backend-invariant (subprocess, 4 devices)
+SCRIPT_BACKEND_WIRE = r"""
+import jax, jax.numpy as jnp
+from repro.train import Strategy
+
+KEY = jax.random.PRNGKey(0)
+W_TRUE = jax.random.normal(KEY, (64, 1))
+def make_batch(t, w):
+    k = jax.random.fold_in(KEY, t * 100 + w)
+    X = jax.random.normal(k, (16, 64))
+    return {"X": X, "y": X @ W_TRUE}
+def grad_fn(params, batch):
+    def loss(p):
+        return jnp.mean((batch["X"] @ p["W"] - batch["y"]) ** 2)
+    return jax.value_and_grad(loss)(params)
+P0 = {"W": jnp.zeros((64, 1)), "b": jnp.zeros((4096,))}
+
+topology = TOPOLOGY
+for comp in ("none", "onebit", "terngrad", "qsgd", "dgc:0.1"):
+    spec = f"bsp/{topology}/{comp}@4"
+    wires = {}
+    for kb in ("ref", "kernel"):
+        eng = Strategy.parse(spec, lr=0.05, backend="device",
+                             wire="measured", kernel_backend=kb
+                             ).build(grad_fn)
+        wires[kb] = eng.run(P0, make_batch, 3)[2]
+    assert wires["ref"] == wires["kernel"] > 0, (spec, wires)
+    print("BACKEND-WIRE-OK", spec)
+"""
+
+
+@pytest.mark.parametrize("topology", TOPOLOGIES + ("fully_connected",))
+def test_measured_wire_backend_invariant_4dev(multidevice, topology):
+    """Every codec inside the ``topology`` schedule puts the same
+    measured bytes on the wire whether its math runs through the jnp
+    oracle or the Pallas kernels."""
+    out = multidevice(SCRIPT_BACKEND_WIRE.replace("TOPOLOGY",
+                                                  repr(topology)), 4)
+    assert out.count("BACKEND-WIRE-OK") == 5, out
+
+
 def test_comm_plane_engine_4dev(multidevice):
     out = multidevice(SCRIPT_ENGINE, 4)
     for marker in ("NONE-BITWISE-OK", "ORDERING-OK", "DGC-PER-STEP-OK",

@@ -116,3 +116,53 @@ def test_data_parallel_engine_8dev(multidevice):
     assert "ENGINE-MATCHES-SIM" in out
     assert "KERNEL-REF-IDENTICAL" in out
     assert "EF-WIRE-OK" in out
+
+
+# ------------------------------- wire = per-event bytes x events (4 devices)
+SCRIPT_WIRE_EVENTS = r"""
+import sys
+import jax, jax.numpy as jnp
+from repro.train import Strategy
+
+KEY = jax.random.PRNGKey(0)
+W_TRUE = jax.random.normal(KEY, (64, 1))
+def make_batch(t, w):
+    k = jax.random.fold_in(KEY, t * 100 + w)
+    X = jax.random.normal(k, (16, 64))
+    return {"X": X, "y": X @ W_TRUE}
+def grad_fn(params, batch):
+    def loss(p):
+        return jnp.mean((batch["X"] @ p["W"] - batch["y"]) ** 2)
+    return jax.value_and_grad(loss)(params)
+P0 = {"W": jnp.zeros((64, 1)), "b": jnp.zeros((4096,)),
+      "c": jnp.zeros((512, 8))}
+
+sync = sys.argv[1]
+for arch in ("allreduce", "ps"):
+    for comp in ("none", "onebit", "dgc:0.05"):
+        strat = Strategy.parse(f"{sync}/{arch}/{comp}@4", lr=0.01,
+                               bucket_mb=0.005, backend="device")
+        eng = strat.build(grad_fn)
+        _, hist, wire = eng.run(P0, make_batch, 2)
+        dev = eng.inner
+        # every event carries the compressor's static per-worker bytes:
+        # all K workers per bsp step, one worker per async event
+        events = len(hist) * (strat.workers if strat.sync == "bsp" else 1)
+        assert wire == dev.per_event_wire_bytes(P0) * events, (
+            strat.spec(), wire, dev.per_event_wire_bytes(P0), events)
+        if strat.sync == "bsp":
+            tl = dev.modeled_timeline(P0)
+            assert tl["n_buckets"] > 1, tl
+            assert tl["overlap_s"] <= tl["no_overlap_s"], (strat.spec(), tl)
+        print("WIRE-EVENTS-OK", strat.spec())
+"""
+
+
+@pytest.mark.parametrize("sync", ["bsp", "ssp:3", "asp"])
+def test_wire_is_per_event_bytes_times_events_4dev(multidevice, sync):
+    """Both architectures and three codecs on the device engine: the
+    wire total is the per-event accounting times the events run, and
+    for BSP the bucketed overlap never models slower than no overlap."""
+    out = multidevice(SCRIPT_WIRE_EVENTS.replace(
+        "sys.argv[1]", repr(sync)), 4)
+    assert out.count("WIRE-EVENTS-OK") == 6, out

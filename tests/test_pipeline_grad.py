@@ -46,6 +46,17 @@ def test_onefb_tick_and_bubble_accounting():
     assert fracs == sorted(fracs, reverse=True)
 
 
+@pytest.mark.parametrize("stages,micro,v", [(2, 8, 2), (4, 8, 2), (4, 6, 2),
+                                            (2, 4, 4)])
+def test_onefb_critical_path_under_gpipe(stages, micro, v):
+    """Interleaved 1F1B against GPipe on the same stages and micro-batch
+    count: its modeled critical path (ticks, each 1/v of a stage's work)
+    and its analytic bubble are both strictly smaller."""
+    assert onefb_ticks(stages, micro, v) / v < gpipe_ticks(stages, micro)
+    assert onefb_bubble_fraction(stages, micro, v) < \
+        bubble_fraction(stages, micro)
+
+
 # --------------------------------------- pipeline grads vs stacked model
 SCRIPT_GRADS = r"""
 import numpy as np, jax, jax.numpy as jnp
