@@ -23,9 +23,14 @@ over all of them, and computes its own experts' part of the result for the
 tokens routed to them, with no capacity: the assignments to held experts
 are sorted by expert into a buffer that holds every assignment a token can
 make to them, and one grouped matmul per projection runs over exactly the
-rows each expert got.  The absent experts' part, and the all-to-all that
-would bring it, are left out; the shared experts are computed alike on
-every member of the group.
+rows each expert got.  Dispatch and combine are a permutation of the
+K-fold repeated tokens and its inverse, so each pass's transpose is a
+gather by the other: neither direction scatters rows of d_model, and a
+token's gradient sums its assignments' rows in fp32.  Buffer rows past
+the held assignments hold any token, and nothing reads what the experts
+make of them.  The absent experts' part, and the all-to-all that would
+bring it, are left out; the shared experts are computed alike on every
+member of the group.
 
 Capacity dispatch serves every configuration without ``experts_held``:
 serving's per-slot decode and the Kimi-K2 and DeepSeek configurations of
@@ -33,6 +38,8 @@ the zoo.  ``tests/test_moe.py`` pins its capacity rule.  A held block
 gets its tokens only from an all-to-all that does not exist yet.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -202,40 +209,100 @@ def _held_experts(p, xt, gate, ids, cfg):
         perm = jnp.argsort(key, stable=True)      # held first, by expert
         rank = jnp.zeros_like(perm).at[perm].set(
             jnp.arange(T * K, dtype=perm.dtype), unique_indices=True)
-        sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+        # counted by comparison: a bincount is a scatter-add of T*K ones
+        sizes = jnp.sum(key[:, None] == jnp.arange(n), axis=0,
+                        dtype=jnp.int32)
         total = jnp.sum(sizes)
-        # each row's token; rows past the held assignments read a zero row
-        src = jnp.where(jnp.arange(rows) < total,
-                        jnp.pad(perm[:M], (0, rows - M)) // K, T)
+        # each row's assignment; rows past the held ones hold any, and
+        # nothing reads what the experts make of them
+        src = jnp.pad(perm[:M], (0, rows - M))
     dt = xt.dtype
-    y = jax.checkpoint(_expert_ffn)(
-        xt, src, sizes, p["w_gate"].astype(dt), p["w_up"].astype(dt),
-        p["w_down"].astype(dt))                                   # [rows, d]
+    y = jax.checkpoint(_expert_ffn, static_argnums=(0,))(
+        K, xt, src, rank, sizes, p["w_gate"].astype(dt),
+        p["w_up"].astype(dt), p["w_down"].astype(dt))             # [rows, d]
     with jax.named_scope("combine"):
-        # each assignment's row of y; an absent expert's reads a zero row
-        at = jnp.where(rank < total, rank, rows).reshape(T, K)
-        out = jax.checkpoint(_combine)(gate, y, at)
+        out = _combine(gate, y, src, rank.reshape(T, K), total)
     dropped = jnp.sum((key < n) & (rank >= M)).astype(jnp.float32)
     load = jnp.max(sizes).astype(jnp.float32) / (T * K / E)
     return out.astype(xt.dtype), dropped, load
 
 
-def _expert_ffn(xt, src, sizes, w_gate, w_up, w_down):
-    """The held experts' SwiGLU over the rows of token ``src`` (``len(xt)``:
-    a zero row), grouped by ``sizes``.  Checkpointed: the backward pass
-    keeps its inputs and recomputes the rest, since every row buffer is
-    sized for the most assignments the held experts could get."""
+def _expert_ffn(K, xt, src, rank, sizes, w_gate, w_up, w_down):
+    """The held experts' SwiGLU over the rows of assignments ``src``,
+    grouped by ``sizes``.  Checkpointed: the backward pass keeps its
+    inputs and recomputes the rest, since every row buffer is sized for
+    the most assignments the held experts could get."""
     with jax.named_scope("dispatch"):
-        buf = jnp.concatenate([xt, jnp.zeros((1, xt.shape[1]), xt.dtype)])[src]
+        buf = _dispatch(K, xt, src, rank, jnp.sum(sizes))
     with jax.named_scope("experts"):
         h = jax.nn.silu(_grouped(buf, w_gate, sizes)) \
             * _grouped(buf, w_up, sizes)
         return _grouped(h, w_down, sizes)
 
 
-def _combine(gate, y, at):
-    """sum_k gate[t, k] * y[at[t, k]] in fp32 (``at == len(y)``: nothing);
-    checkpointed, so the backward pass keeps y and not the gathered
-    [T, K, d] rows."""
-    y = jnp.concatenate([y, jnp.zeros((1, y.shape[1]), y.dtype)])
-    return jnp.einsum("tk,tkd->td", gate, y[at].astype(jnp.float32))
+# Dispatch and combine move rows by a permutation of the K-fold repeated
+# tokens (``src`` the assignment of each buffer row, ``rank`` its inverse:
+# the row of each assignment, held where below ``total``).  The transpose
+# of a permutation is its inverse, so each VJP is a gather too and the
+# backward pass holds no scatter.  Rows of the experts' output, and of its
+# cotangent, past ``total`` hold no defined value (``_grouped``): both
+# directions mask them with ``where``, never a multiply.  A token's K rows
+# are gathered a column k at a time, K slabs of [T, d] summed elementwise
+# in fp32: a [T, K, d] gather puts K (6) in the second-minor dimension of
+# the tiled layout, and XLA relayouts and writes out its fp32 copy.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(K, xt, src, rank, total):
+    """Buffer row r: token ``src[r] // K`` of ``xt``."""
+    return xt[src // K]
+
+
+def _dispatch_fwd(K, xt, src, rank, total):
+    return xt[src // K], (rank, total)
+
+
+def _dispatch_bwd(K, res, g):
+    # a token's gradient: the sum, in fp32, of its held assignments' rows
+    rank, total = res
+    dxt = sum(r.astype(jnp.float32) for r in _rows(g, rank.reshape(-1, K),
+                                                   total))
+    return dxt.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _rows(y, at, total):
+    """For each column k of ``at`` [T, K], the rows ``y[at[:, k]]``, zero
+    where ``at`` is past ``total``."""
+    return [jnp.where((a < total)[:, None], y[jnp.minimum(a, len(y) - 1)],
+                      jnp.zeros((), y.dtype)) for a in at.T]
+
+
+@jax.custom_vjp
+def _combine(gate, y, src, rank, total):
+    """sum_k gate[t, k] * y[rank[t, k]] in fp32, over the held
+    assignments."""
+    return sum(g[:, None] * r.astype(jnp.float32)
+               for g, r in zip(gate.T, _rows(y, rank, total)))
+
+
+def _combine_fwd(gate, y, src, rank, total):
+    return _combine(gate, y, src, rank, total), (gate, y, src, rank, total)
+
+
+def _combine_bwd(res, dout):
+    gate, y, src, rank, total = res
+    # dout is the cotangent of ``out.astype(y.dtype)`` and holds values
+    # of that dtype, so it is gathered and multiplied in it with no loss
+    dout = dout.astype(y.dtype)
+    dgate = jnp.stack([jnp.einsum("td,td->t", dout, r,
+                                  preferred_element_type=jnp.float32)
+                       for r in _rows(y, rank, total)], axis=1)
+    dy = gate.reshape(-1)[src][:, None] \
+        * dout[src // gate.shape[1]].astype(jnp.float32)
+    dy = jnp.where((jnp.arange(len(y)) < total)[:, None], dy, 0.0)
+    return dgate, dy.astype(y.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)

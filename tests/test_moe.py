@@ -1,5 +1,6 @@
 """MoE dispatch invariants (the §Perf pair-3 code path)."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,139 @@ def test_moe_grads_flow_to_experts_and_router():
     assert float(jnp.abs(g["w_gate"]).sum()) > 0
     assert float(jnp.abs(g["w_down"]).sum()) > 0
     assert float(jnp.abs(g["router"]["w"]).sum()) > 0
+
+
+# ---- held-expert dispatch: gradients against a masked-dense formulation
+
+def _held_cfg(K, n, E=16, first=4):
+    return dataclasses.replace(_cfg(E=E, K=K), experts_held=(first, n))
+
+
+def _masked_dense(p, x, cfg):
+    """Every held expert on every token, masked by the token's gate."""
+    from repro.models.moe import route
+    first, n = cfg.experts_held
+    xt = x.reshape(-1, cfg.d_model)
+    gate, ids, _ = route(p["router"]["w"], x, cfg)
+    held = jnp.arange(first, first + n)
+    g = jnp.sum(jnp.where(ids[..., None] == held, gate[..., None], 0.0), 1)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", xt, p["w_gate"]))
+    h = h * jnp.einsum("td,edf->tef", xt, p["w_up"])
+    y = jnp.einsum("tef,efd->ted", h, p["w_down"])
+    return jnp.einsum("te,ted->td", g, y).reshape(x.shape)
+
+
+def _nan_past(a, sizes):
+    return jnp.where((jnp.arange(len(a)) < jnp.sum(sizes))[:, None], a,
+                     jnp.nan)
+
+
+@jax.custom_vjp
+def _poisoned_grouped(lhs, rhs, sizes):
+    """``ragged_dot`` with NaN in every row past ``sum(sizes)``, in the
+    output and in the rows' cotangent, as the grouped matmul leaves them
+    undefined; the weights' cotangent reads the grouped rows alone."""
+    return _nan_past(jax.lax.ragged_dot(lhs, rhs, sizes), sizes)
+
+
+def _poisoned_fwd(lhs, rhs, sizes):
+    return _poisoned_grouped(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+
+def _poisoned_bwd(res, g):
+    lhs, rhs, sizes = res
+    live = (jnp.arange(len(lhs)) < jnp.sum(sizes))[:, None]
+    _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes),
+                     jnp.where(live, lhs, 0.0), rhs)
+    dlhs, drhs = vjp(jnp.where(live, g, 0.0))
+    return _nan_past(dlhs, sizes), drhs, None
+
+
+_poisoned_grouped.defvjp(_poisoned_fwd, _poisoned_bwd)
+
+HELD_CASES = {          # (K, n, router skew)
+    "n>K": (2, 4, None),
+    "n<K": (3, 2, None),
+    "n=K": (3, 3, None),
+    "one-expert": (3, 4, "one"),    # every token to held expert 1
+    "full-buffer": (3, 4, "full"),  # every choice a held expert
+}
+
+
+@pytest.mark.parametrize("poison", [False, True], ids=["plain", "poison"])
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_held_experts_match_masked_dense_with_gradients(case, poison,
+                                                        monkeypatch, request):
+    """The held path's output and its gradients with respect to x, the
+    router and the three expert weights equal autodiff of the masked-dense
+    formulation; with NaN in the grouped matmuls' undefined rows
+    (``poison``) nothing changes and everything stays finite."""
+    import repro.models.moe as moe
+    if poison:
+        monkeypatch.setattr(moe, "_grouped", _poisoned_grouped)
+        # the checkpointed FFN's trace is cached by function: drop it
+        # before and after, so each case traces the ``_grouped`` it sets
+        jax.clear_caches()
+        request.addfinalizer(jax.clear_caches)
+    K, n, skew = HELD_CASES[case]
+    cfg = _held_cfg(K, n)
+    first = cfg.experts_held[0]
+    key = jax.random.PRNGKey(11)
+    p = moe_init(key, cfg)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 24, cfg.d_model))
+    if skew:
+        # logits ~10 above the rest: the choices are the held experts',
+        # with softmax short of saturation so the router's gradient lives
+        x = jnp.abs(x)
+        w = p["router"]["w"]
+        p["router"] = {"w": w.at[:, first + 1].add(0.4) if skew == "one"
+                       else w.at[:, first:first + n].add(0.4)}
+    ct = jax.random.normal(jax.random.fold_in(key, 2), x.shape)
+
+    def grads(f):
+        def loss(pp, xx):
+            out, stats = f(pp, xx)
+            return jnp.sum(out * ct), (out, stats)
+        return jax.jit(jax.grad(loss, (0, 1), has_aux=True))(p, x)
+    with jax.default_matmul_precision("highest"):
+        got_g, (out, stats) = grads(lambda pp, xx: moe_apply(pp, xx, cfg))
+        want_g, (want, _) = grads(
+            lambda pp, xx: (_masked_dense(pp, xx, cfg), {}))
+    assert float(stats["dropped"]) == 0
+    if skew == "full":
+        ids = moe.route(p["router"]["w"], x, cfg)[1]
+        assert bool(jnp.all((ids >= first) & (ids < first + n)))
+    if skew == "one":
+        assert float(stats["held_load"]) == pytest.approx(16 / 3)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-4,
+                               atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    leaves = {"x": (got_g[1], want_g[1])}
+    for k in ("w_gate", "w_up", "w_down"):
+        leaves[k] = (got_g[0][k], want_g[0][k])
+    leaves["router"] = (got_g[0]["router"]["w"], want_g[0]["router"]["w"])
+    for name, (a, e) in leaves.items():
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(e))) > 0, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(e), rtol=1e-4,
+            atol=1e-5 * float(jnp.max(jnp.abs(e))), err_msg=name)
+
+
+@pytest.mark.parametrize("K,n", [(2, 4), (3, 2)], ids=["n>K", "n<K"])
+def test_held_experts_gradient_has_no_scatter_add(K, n):
+    """Dispatch and combine are a permutation and its inverse, so the
+    gradient of the held path, gate and ids given, gathers and never
+    scatter-adds."""
+    from repro.models.moe import _held_experts
+    cfg = _held_cfg(K, n)
+    p = moe_init(jax.random.PRNGKey(0), cfg)
+    T = 48
+    xt = jnp.zeros((T, cfg.d_model))
+    gate = jnp.zeros((T, K))
+    ids = jnp.zeros((T, K), jnp.int32)
+
+    def loss(p, xt, gate):
+        return jnp.sum(_held_experts(p, xt, gate, ids, cfg)[0])
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(p, xt, gate))
+    assert "gather" in jaxpr
+    assert "scatter-add" not in jaxpr

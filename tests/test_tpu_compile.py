@@ -14,6 +14,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,8 +97,6 @@ def _assert_fwd_bwd_kernels(q, k, v, **kw):
     instruction named ``attention_grad`` (what the benchmark's forward
     rooflines read), and the backward's dK/dV and dQ kernels are named
     ``flash_attention_bwd`` (what ``flash_bwd_roofline`` reads)."""
-    import re
-
     def loss(q, k, v):
         def layer(c, _):
             out = attention_grad(q, k, v, interpret=False, **kw)
@@ -142,7 +141,8 @@ def test_deepseek_train_step(topo, one_chip, monkeypatch):
     """The DeepSeek-V2 cell's whole training step (1 dense + 4 MoE layers
     holding 8 of 64 experts, vocabulary 12800, 2 rows of 4096 tokens, bf16
     compute) fits one chip, with the flash forward and backward and the
-    held experts' grouped matmuls as Mosaic kernels."""
+    held experts' grouped matmuls as Mosaic kernels, and with no scatter
+    of d_model-wide rows in the held experts' dispatch or combine."""
     import dataclasses
 
     import repro.models.mla as mla
@@ -183,6 +183,14 @@ def test_deepseek_train_step(topo, one_chip, monkeypatch):
     assert "jit(flash_attention_bwd)/pallas_call" in text
     assert "/moe/experts/jit(gmm)/pallas_call" in text
     assert "jit(tgmm)/pallas_call" in text
+    # dispatch and combine move d_model-wide rows by gathers alone, both
+    # ways; the backward pass sorts nothing there
+    moved = [ln for ln in text.splitlines()
+             if re.search(r"/moe/(dispatch|combine)/", ln)]
+    assert not [ln for ln in moved if re.search(r"\bscatter\(", ln)
+                and re.search(r"\[[\d,]*\b2048\b", ln)]
+    assert not [ln for ln in moved if re.search(r"\bsort\(", ln)
+                and "transpose(" in ln]
 
 
 @pytest.mark.parametrize("window", [0, 512], ids=["full", "window"])
